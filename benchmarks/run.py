@@ -23,6 +23,9 @@ Executor backends (identical rows, different scaling): ``--executor
 serial``, ``--executor process`` (one worker per cell, the default), or
 ``--executor 'sharded[shards=4]'`` / ``--shards 4`` (split each cell's
 trace by arrival time across workers — the 1M+-job single-cell path).
+Worker processes never touch the TPU: on a TPU host the default becomes
+``serial`` when a cell needs the device, and ``process``/``sharded``
+refuse such cells. The sweep exits non-zero when any cell failed.
 
 Experiment plans are JSON artifacts: ``--save-plan plan.json`` writes the
 sweep's (scenarios × policies × seeds) grid without running it;
@@ -126,7 +129,8 @@ def print_metrics_table(snap) -> None:
         print(f"# {k}: {v:.0f}")
 
 
-def run_sweep(args) -> None:
+def run_sweep(args) -> int:
+    """Run the sweep; returns the number of failed cells."""
     from repro import experiments
 
     if args.plan:
@@ -136,8 +140,8 @@ def run_sweep(args) -> None:
     if args.save_plan:
         plan.save(args.save_plan)
         print(f"# plan ({len(plan.cells())} cells) -> {args.save_plan}")
-        return
-    executor = args.executor
+        return 0
+    executor = args.executor or experiments.default_executor(plan.cells())
     options = {}
     if args.shards is not None:
         executor = executor if executor.startswith("sharded") else "sharded"
@@ -177,6 +181,7 @@ def run_sweep(args) -> None:
     if trace_path is not None:
         print(f"# trace -> {trace_path} (load in https://ui.perfetto.dev "
               f"or: PYTHONPATH=src python -m repro.obs.report {trace_path})")
+    return len(failed)
 
 
 def main() -> None:
@@ -195,9 +200,12 @@ def main() -> None:
                     default="baseline,least-load,ecovisor,waterwise",
                     help="comma-separated policy specs, e.g. "
                          "'baseline,waterwise[lam_h2o=0.7,backend=jax]'")
-    ap.add_argument("--executor", default="process",
+    ap.add_argument("--executor", default=None,
                     help="executor spec: serial | process[max_workers=N] | "
-                         "sharded[shards=N,max_workers=N,handoff_s=S]")
+                         "sharded[shards=N,max_workers=N,handoff_s=S] | "
+                         "device[devices=N,max_cells=N] (default: process; "
+                         "serial on a TPU host when a cell needs the "
+                         "device)")
     ap.add_argument("--shards", type=int, default=None,
                     help="shortcut: run with the sharded executor at N "
                          "shards per cell")
@@ -271,6 +279,8 @@ def main() -> None:
     if args.list_forecasters:
         list_forecasters(args.markdown)
         return
+    from repro.runtime.platform import use_compile_cache
+    use_compile_cache()
     if args.serve:
         from benchmarks import serve_bench
         raise SystemExit(serve_bench.main([]))
@@ -278,8 +288,7 @@ def main() -> None:
         sweep_flags = dict(sweep=args.sweep, scenarios=args.scenarios != "",
                            schedulers=args.schedulers
                            != ap.get_default("schedulers"),
-                           executor=args.executor
-                           != ap.get_default("executor"),
+                           executor=args.executor is not None,
                            shards=args.shards is not None,
                            seeds=args.seeds != "", plan=args.plan != "",
                            save_plan=args.save_plan != "",
@@ -310,7 +319,8 @@ def main() -> None:
         if args.only:
             ap.error("--only does not apply with --sweep "
                      "(use --scenarios/--schedulers to filter)")
-        run_sweep(args)
+        if run_sweep(args):
+            raise SystemExit(1)
         return
     sweep_only = dict(scenarios=args.scenarios != "", days=args.days is not None,
                       jobs_per_day=args.jobs_per_day is not None,
@@ -322,7 +332,7 @@ def main() -> None:
                       shards=args.shards is not None,
                       seeds=args.seeds != "",
                       save_plan=args.save_plan != "",
-                      executor=args.executor != ap.get_default("executor"),
+                      executor=args.executor is not None,
                       schedulers=args.schedulers
                       != ap.get_default("schedulers"))
     if any(sweep_only.values()):

@@ -19,6 +19,7 @@ import sys
 import repro.obs as obs
 from repro.core import telemetry
 from repro.policy.pipeline import forecast_pipeline
+from repro.runtime.platform import use_compile_cache
 from repro.serve import DecisionLoop, PoissonBurstArrivals, ServeConfig
 from repro.sim.engine import EventSimulator, SimConfig
 from repro.sim.trace import scale_capacity_for_utilization
@@ -41,6 +42,7 @@ def main() -> int:
                     help="exit 1 unless zero deadline misses and non-empty "
                          "round metrics (the CI smoke contract)")
     args = ap.parse_args()
+    use_compile_cache()
 
     tele = telemetry.generate(days=1, seed=0)
     rate = args.jobs_per_day / 86400.0

@@ -99,9 +99,22 @@ def _pad0(x, pad: int, value=0):
 
 
 def _interpret(impl: str, interpret: Optional[bool]) -> bool:
+    """Interpret mode applies to the Pallas kernel only, and defaults to on
+    exactly when the backend is not a TPU."""
+    if impl != "pallas":
+        return False
     if interpret is not None:
         return bool(interpret)
-    return impl == "pallas" and not runtime_platform.on_tpu()
+    return not runtime_platform.on_tpu()
+
+
+def _count_sinkhorn(impl: str, interpret: bool, n: int = 1) -> None:
+    """Count solves by the Sinkhorn implementation that actually ran:
+    ``round.sinkhorn/pallas`` (the compiled kernel), ``/pallas_interpret``
+    or ``/xla``. Always live, like every ``obs`` counter, so a run can
+    prove which kernel served it without enabling tracing."""
+    mode = "pallas_interpret" if impl == "pallas" and interpret else impl
+    obs.counter(f"round.sinkhorn/{mode}", n)
 
 
 # ---------------------------------------------------------------------------
@@ -233,6 +246,7 @@ def fused_solve(cost: np.ndarray, allowed: np.ndarray, capacity: np.ndarray,
             return _infeasible(M)
         _, pad = _pad_rows(M)
         impl = sinkhorn_impl or sinkhorn_impl_default()
+        interp = _interpret(impl, interpret)
         arcs = np.stack([
             _pad0(cost, pad),
             _pad0(allowed.astype(np.float64), pad),
@@ -245,8 +259,9 @@ def fused_solve(cost: np.ndarray, allowed: np.ndarray, capacity: np.ndarray,
             jnp.asarray(arcs), jnp.asarray(tolv),
             jnp.asarray(cap, jnp.float32),
             soften=bool(soften), sigma=float(sigma), impl=impl,
-            eps_min=float(eps_min), interpret=_interpret(impl, interpret))
+            eps_min=float(eps_min), interpret=interp)
         Cn, X = jax.device_get((Cn, X))
+        _count_sinkhorn(impl, interp)
         if obs.enabled():
             bucket = M + 1 + pad
             obs.annotate(
@@ -254,7 +269,8 @@ def fused_solve(cost: np.ndarray, allowed: np.ndarray, capacity: np.ndarray,
                 sinkhorn_iters=jax_solver.SINKHORN_ITERS
                 * jax_solver.SINKHORN_STAGES,
                 eps0=jax_solver.SINKHORN_EPS0, eps_min=eps_min,
-                anneal_stages=jax_solver.SINKHORN_STAGES, impl=impl)
+                anneal_stages=jax_solver.SINKHORN_STAGES, impl=impl,
+                interpret=interp)
         c_eff, mask = jax_solver._effective(cost, allowed, soften, overrun,
                                             tol, sigma)
         res = jax_solver._finalize(np.asarray(X[:M], np.float64),
@@ -332,13 +348,13 @@ def _batch_callable(devices: int, *, soften: bool, sigma: float, impl: str,
                             impl=impl, eps_min=eps_min, interpret=interpret)
     fn = jax.vmap(one)
     if devices > 1:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh
         from jax.sharding import PartitionSpec as P
         mesh = Mesh(np.asarray(jax.devices()[:devices]), ("cells",))
-        fn = shard_map(fn, mesh=mesh,
-                       in_specs=(P("cells"), P("cells"), P("cells")),
-                       out_specs=(P("cells"), P("cells")), check_rep=False)
+        fn = jax.shard_map(fn, mesh=mesh,
+                           in_specs=(P("cells"), P("cells"), P("cells")),
+                           out_specs=(P("cells"), P("cells")),
+                           check_vma=False)
     return jax.jit(fn)
 
 
@@ -426,6 +442,7 @@ def fused_round_batch(requests, devices: int = 1) -> list:
             compiles = fn._cache_size() - before
             if compiles:
                 obs.counter("round.batch_compile", compiles)
+            _count_sinkhorn(statics["impl"], statics["interpret"], B)
             Cnb, Xb = jax.device_get(out)
             for b, i in enumerate(idxs):
                 r = requests[i]
@@ -639,14 +656,19 @@ def fused_temporal_round(inst, now_s: float, ci, ewif, wue, pue, wsf,
 
     cap = np.asarray(inst.capacity, np.int64)
     bucket, _ = _pad_rows(M)
-    impl = sinkhorn_impl or sinkhorn_impl_default()
+    # The warm-startable adaptive program runs the XLA loop only; the span
+    # names the implementation that actually runs.
+    impl = ("xla" if warm_start is not None
+            else sinkhorn_impl or sinkhorn_impl_default())
+    interp = _interpret(impl, interpret)
 
     with obs.timed("solver.fused_round", jobs=M, slots=S, regions=N,
                   bucket=bucket, occupancy=(M + 1) / bucket,
                   sinkhorn_iters=jax_solver.SINKHORN_ITERS
                   * jax_solver.SINKHORN_STAGES,
                   eps0=jax_solver.SINKHORN_EPS0, eps_min=eps_min,
-                  anneal_stages=jax_solver.SINKHORN_STAGES, impl=impl) as t:
+                  anneal_stages=jax_solver.SINKHORN_STAGES, impl=impl,
+                  interpret=interp) as t:
         # One zero-initialized padded blob, filled in place: padding rows fall
         # out as zero-mass (validity 0) rows and the whole round uploads as two
         # contiguous copies (blob + rattrs).
@@ -703,8 +725,9 @@ def fused_temporal_round(inst, now_s: float, ci, ewif, wue, pue, wsf,
             out = _temporal_program(
                 jnp.asarray(blob), jnp.asarray(rattrs), **statics,
                 want_plan=bool(want_plan), impl=impl, eps_min=float(eps_min),
-                interpret=_interpret(impl, interpret))
+                interpret=interp)
             out = jax.device_get(out)
+        _count_sinkhorn(impl, interp)
         Cn = np.asarray(out[0][:M], np.float64)
         X = np.asarray(out[1][:M], np.float64)
         scale = float(out[2])

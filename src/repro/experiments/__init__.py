@@ -30,8 +30,9 @@ survives as thin shims over this package.
 """
 from repro.experiments.executor import (Executor, ProcessExecutor,
                                         SerialExecutor, ShardedExecutor,
-                                        describe_executors, executor_schema,
-                                        get_executor, list_executors)
+                                        default_executor, describe_executors,
+                                        executor_schema, get_executor,
+                                        list_executors, needs_device)
 from repro.experiments.plan import (CSV_COLS, TABLE_COLS, Cell,
                                     ExperimentPlan, aggregate_seeds,
                                     attach_savings, seed_group_key, t95,
@@ -58,7 +59,7 @@ __all__ = [
     # executors
     "Executor", "SerialExecutor", "ProcessExecutor", "ShardedExecutor",
     "get_executor", "list_executors", "executor_schema",
-    "describe_executors",
+    "describe_executors", "default_executor", "needs_device",
     # sharding
     "run_sharded_cell", "auto_handoff_s", "merge_forecast_stats",
     "states_match",

@@ -26,20 +26,109 @@ all speak the same validated language as policies and scenarios.
 A crashed cell never aborts the others on any backend: its row carries the
 failure in the ``error`` column and execution continues (the old sweep's
 bare ``f.result()`` abort is gone).
+
+One process per chip: a TPU belongs to one process at a time, so worker
+processes (``process``, and the ``sharded`` backend's pool) are held to
+JAX's CPU backend, and on a TPU host those backends refuse cells that
+dispatch device work (:func:`needs_device`) instead of running them on a
+CPU fallback in the child. ``serial`` and ``device`` run in-process.
 """
 from __future__ import annotations
 
 import concurrent.futures
+import multiprocessing
 import os
+import sys
 import threading
-from typing import Dict, List, Optional, Union
+from typing import Dict, List, Optional, Sequence, Union
 
 import repro.obs as obs
 from repro.core import solvers
 from repro.experiments import runner
 from repro.experiments.plan import Cell
+from repro.launch import devices as launch_devices
+from repro.runtime import platform as runtime_platform
 from repro.spec import (Param, parse_raw, params_from_signature,
                         unknown_name_error, validate_params)
+
+# Solver backends that never dispatch JAX work, and forecasters fit in
+# numpy; every other backend or forecaster runs on the JAX device.
+_HOST_BACKENDS = frozenset({"flow", "scipy", "pulp"})
+_HOST_FORECASTERS = frozenset({"oracle", "seasonal-naive", "persistence"})
+
+
+def _policy_params(cell: Cell):
+    """The cell's policy registry entry and its parameters, defaults filled
+    in."""
+    from repro import policy
+    spec = policy.as_spec(cell.policy)
+    entry = policy.get_policy(spec.name)
+    params = {name: p.default for name, p in entry.params.items()}
+    params.update(spec.params)
+    return entry, params
+
+
+def needs_device(cell: Cell) -> bool:
+    """True when running ``cell`` dispatches JAX work: a solver backend
+    other than the host-only ones, or a forecast-driven policy whose
+    forecaster is fit with JAX. Anything unclassifiable counts as needing
+    the device."""
+    try:
+        entry, params = _policy_params(cell)
+    except Exception:                       # noqa: BLE001 — conservative
+        return True
+    if "backend" in params and params["backend"] not in _HOST_BACKENDS:
+        return True
+    return bool(entry.forecast_driven
+                and params.get("forecaster") not in _HOST_FORECASTERS)
+
+
+def _device_cells_on_tpu(cells: Sequence[Cell]) -> List[Cell]:
+    """The cells that need the device, if this is a TPU host (else none).
+    Probes the platform only when such a cell exists."""
+    device = [c for c in cells if needs_device(c)]
+    return device if device and runtime_platform.on_tpu() else []
+
+
+def refuse_device_cells(cells: Sequence[Cell], executor: str) -> None:
+    """Raise before any worker starts when ``executor`` would hand a cell
+    that needs the device to a worker process on a TPU host."""
+    device = _device_cells_on_tpu(cells)
+    if device:
+        names = ", ".join(sorted({str(c.policy) for c in device}))
+        raise RuntimeError(
+            f"the {executor} executor runs cells in worker processes, and "
+            f"on a TPU host the chip belongs to one process: {len(device)} "
+            f"cell(s) need the device ({names}); run them with "
+            f"--executor serial or --executor 'device[...]'")
+
+
+def default_executor(cells: Sequence[Cell]) -> str:
+    """The sweep default: fan out over processes, except on a TPU host
+    when a cell needs the device — then run in-process."""
+    return "serial" if _device_cells_on_tpu(cells) else "process"
+
+
+def _pin_cpu() -> None:
+    """Pool-worker initializer: hold the worker to JAX's CPU backend, so it
+    can never initialise the accelerator its parent holds."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:
+        import jax
+        jax.config.update("jax_platforms", "cpu")
+
+
+def host_pool(workers: int) -> concurrent.futures.ProcessPoolExecutor:
+    """A process pool for host-only work. Workers start CPU-pinned; when
+    this process already holds an accelerator backend they are spawned
+    fresh rather than forked from it (a forked child would inherit the
+    live device client)."""
+    ctx = None
+    if launch_devices.backend_initialized() \
+            and runtime_platform.platform() != "cpu":
+        ctx = multiprocessing.get_context("spawn")
+    return concurrent.futures.ProcessPoolExecutor(
+        workers, mp_context=ctx, initializer=_pin_cpu)
 
 
 class Executor:
@@ -71,7 +160,9 @@ class ProcessExecutor(Executor):
 
     ``max_workers=0`` auto-sizes to ``min(cpu_count, len(cells))``. Serial
     and process runs produce identical rows: every cell is deterministic
-    in its specs and rebuilt from primitives inside the worker.
+    in its specs and rebuilt from primitives inside the worker. Workers
+    are CPU-pinned; on a TPU host a plan with cells that need the device
+    is refused (``refuse_device_cells``).
     """
 
     name = "process"
@@ -83,9 +174,10 @@ class ProcessExecutor(Executor):
         workers = self.max_workers or min(os.cpu_count() or 1, len(cells))
         if workers <= 1 or len(cells) <= 1:
             return SerialExecutor().run(cells)
+        refuse_device_cells(cells, self.name)
         rows: List[Dict] = []
         fn = runner.run_cell_obs if obs.enabled() else runner.run_cell
-        with concurrent.futures.ProcessPoolExecutor(workers) as pool:
+        with host_pool(workers) as pool:
             futs = [pool.submit(fn, c) for c in cells]
             for cell, fut in zip(cells, futs):
                 try:
@@ -227,19 +319,11 @@ class DeviceExecutor(Executor):
         and never reaches ``solvers.solve``, so a barrier slot for them
         could deadlock the wave. Anything unclassifiable is non-batchable
         (clean fallback beats a wrong classification)."""
-        from repro import policy
         try:
-            spec = policy.as_spec(cell.policy)
-            entry = policy.get_policy(spec.name)
-            if entry.forecast_driven:
-                return False
-            backend = spec.params.get("backend")
-            if backend is None:
-                p = entry.params.get("backend")
-                backend = None if p is None else p.default
-            return backend == "fused"
+            entry, params = _policy_params(cell)
         except Exception:                   # noqa: BLE001 — conservative
             return False
+        return not entry.forecast_driven and params.get("backend") == "fused"
 
     def _run_threaded(self, cell: Cell, i: int, rows: List,
                       batcher: _CellBatcher) -> None:

@@ -41,7 +41,6 @@ value, not guaranteed to the last bit — float association differs).
 """
 from __future__ import annotations
 
-import concurrent.futures
 import contextlib
 import os
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -50,6 +49,7 @@ import numpy as np
 
 import repro.obs as obs
 from repro import policy
+from repro.experiments.executor import host_pool, refuse_device_cells
 from repro.experiments.plan import Cell
 from repro.experiments.runner import (execute, finalize_row, forecast_stats,
                                       resolve_policy_spec)
@@ -329,7 +329,8 @@ def run_sharded_cell(cell: Cell, *, shards: int = 2,
             n = len(slices)
             workers = max_workers or min(os.cpu_count() or 1, n)
             if workers > 1:
-                with concurrent.futures.ProcessPoolExecutor(workers) as pool:
+                refuse_device_cells([cell], "sharded")
+                with host_pool(workers) as pool:
                     futs = [pool.submit(_run_shard, cell, str(spec), boundaries,
                                         handoff_s, k, collect)
                             for k in range(n)]

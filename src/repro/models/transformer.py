@@ -29,32 +29,15 @@ BIG_WINDOW = 1 << 30
 
 
 def _current_mesh():
-    """Version-compat mesh lookup.
-
-    ``jax.sharding.get_abstract_mesh`` landed after the pinned JAX release;
-    on older versions the mesh in effect is the thread-local physical mesh
-    pushed by ``with Mesh(...):`` (and, under the sharding-in-types mode,
-    the internal abstract mesh). Returns None when no mesh is active.
-    """
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        mesh = getter()
-        if mesh is not None and mesh.axis_names:
-            return mesh
-        # An empty abstract mesh does not rule out a `with Mesh(...)`
-        # context: fall through to the thread-local physical mesh.
-    try:
-        from jax._src import mesh as _mesh_internal
-        phys = _mesh_internal.thread_resources.env.physical_mesh
-        if phys is not None and phys.axis_names:
-            return phys
-        abstract_getter = getattr(_mesh_internal, "get_abstract_mesh", None)
-        if abstract_getter is not None:
-            mesh = abstract_getter()
-            if mesh is not None and getattr(mesh, "axis_names", ()):
-                return mesh
-    except Exception:
-        return None
+    """The mesh in effect, or None outside one: the abstract mesh when set,
+    else the physical mesh a ``with Mesh(...):`` block pushes."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is not None and mesh.axis_names:
+        return mesh
+    from jax._src import mesh as _mesh_internal
+    phys = _mesh_internal.thread_resources.env.physical_mesh
+    if phys is not None and phys.axis_names:
+        return phys
     return None
 
 

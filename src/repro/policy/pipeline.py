@@ -689,12 +689,17 @@ class PolicyPipeline:
 
     # -- offline replay ------------------------------------------------------
 
-    def _record(self, cost, allowed, capacity, overrun, tol, soften) -> None:
+    def _record(self, cost, allowed, capacity, overrun, tol, soften,
+                res: solvers.SolveResult) -> None:
+        """Capture the solved instance plus the decision it produced
+        (``assign``, ``objective``), so a replay can hold the served
+        decision against another backend on the same instance."""
         if self.record_windows:
             self.recorded.append(dict(
                 cost=np.array(cost), allowed=np.array(allowed),
                 capacity=np.array(capacity), overrun=np.array(overrun),
-                tol=np.array(tol), soften=bool(soften)))
+                tol=np.array(tol), soften=bool(soften),
+                assign=np.array(res.assign), objective=float(res.objective)))
 
     def replay_recorded(self, backend: str = "jax") -> List[solvers.SolveResult]:
         """Re-solve every recorded scheduling window through the batched
@@ -768,7 +773,7 @@ class PolicyPipeline:
                                     sigma=self.sigma)
             if res.feasible:
                 self._record(plan.cost, plan.allowed, plan.capacity,
-                             plan.overrun, tol, False)
+                             plan.overrun, tol, False, res)
             else:                                            # lines 10-11
                 # Soft fallback is slot-0 only: a job that must overrun its
                 # tolerance should pay the Eq 12-13 penalty and run *now*,
@@ -786,7 +791,7 @@ class PolicyPipeline:
                                     overrun=inst.overrun, tol=tol,
                                     sigma=self.sigma)
                 self._record(cost0, inst.allowed, capacity, inst.overrun,
-                             tol, True)
+                             tol, True, res)
             obs.annotate(softened=softened, status=res.status)
         self.solve_times.append(res.solve_time_s)
 

@@ -307,6 +307,94 @@ def test_process_executor_survives_worker_crash(crash_scenario):
     assert rows[1]["error"] == "" and rows[1]["jobs"] > 0
 
 
+@pytest.mark.parametrize("cells,code", [
+    ("crash-test,diurnal[days=0.02]", 1),
+    ("diurnal[days=0.02]", None),
+])
+def test_sweep_cli_exits_nonzero_when_a_cell_failed(crash_scenario,
+                                                    monkeypatch, cells, code):
+    from repro.runtime import platform
+
+    monkeypatch.syspath_prepend(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    from benchmarks import run as bench_run
+
+    monkeypatch.setattr(platform, "use_compile_cache", lambda: "")
+    monkeypatch.setattr("sys.argv", [
+        "run.py", "--sweep", "--scenarios", cells, "--schedulers",
+        "baseline", "--executor", "serial"])
+    if code is None:
+        bench_run.main()
+    else:
+        with pytest.raises(SystemExit) as ei:
+            bench_run.main()
+        assert ei.value.code == code
+
+
+# ---------------------------------------------------------------------------
+# One process per chip: no pool worker takes the TPU
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("spec,device", [
+    ("baseline", False),
+    ("waterwise", False),                           # backend=flow default
+    ("waterwise[backend=fused]", True),
+    ("waterwise[backend=jax]", True),
+    ("waterwise-forecast", True),                   # Holt-Winters in JAX
+    ("waterwise-forecast[forecaster=seasonal-naive,backend=flow]", False),
+])
+def test_needs_device_classifies_policies(spec, device):
+    cell = experiments.ExperimentPlan.build([CELL], [spec]).cells()[0]
+    assert experiments.needs_device(cell) is device
+
+
+@pytest.fixture()
+def tpu_host(monkeypatch):
+    """The platform probe answers "tpu"; the backend stays the CPU."""
+    from repro.runtime import platform
+    monkeypatch.setattr(platform, "on_tpu", lambda: True)
+
+
+def test_pool_executors_refuse_device_cells_on_a_tpu_host(tpu_host,
+                                                         monkeypatch):
+    from repro.experiments import executor
+
+    plan = experiments.ExperimentPlan.build(
+        [CELL], ["baseline", "waterwise[backend=fused]"])
+    assert experiments.default_executor(plan.cells()) == "serial"
+    with pytest.raises(RuntimeError, match="chip belongs to one process"):
+        plan.run(executor="process[max_workers=2]")
+    # Only stateless policies reach the sharded backend's pool, and none of
+    # the registered ones needs the device: pretend one does.
+    monkeypatch.setattr(executor, "needs_device", lambda cell: True)
+    row, = experiments.ExperimentPlan.build([CELL], ["baseline"]).run(
+        executor="sharded[shards=2]")
+    assert "chip belongs to one process" in row["error"]
+
+
+def test_host_only_cells_still_fan_out_on_a_tpu_host(tpu_host):
+    plan = experiments.ExperimentPlan.build(
+        [CELL], ["baseline", "waterwise[backend=flow]"])
+    assert experiments.default_executor(plan.cells()) == "process"
+    serial = plan.run(executor="serial")
+    for s, p in zip(serial, plan.run(executor="process[max_workers=2]")):
+        _assert_rows_match(s, p)
+
+
+def _worker_platforms():
+    import jax
+    return os.environ["JAX_PLATFORMS"], jax.config.jax_platforms
+
+
+def test_pool_workers_are_held_to_the_cpu(monkeypatch):
+    from repro.experiments.executor import host_pool
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")     # inherited by the worker
+    with host_pool(1) as pool:
+        assert pool.submit(_worker_platforms).result(timeout=120) == \
+            ("cpu", "cpu")
+
+
 # ---------------------------------------------------------------------------
 # Shard-merged forecast/deferral fields (satellite: job-weighted, never
 # dropped when only some shards defer)

@@ -87,7 +87,9 @@ def test_ssd_chunked_model_path_matches_naive():
 
 
 @pytest.mark.parametrize("S,W,chunk", [(64, 32, 16), (128, 128, 64),
-                                       (32, 256, 32)])
+                                       (32, 256, 32),
+                                       (200, 16, 128),   # end padding
+                                       (50, 16, 12)])    # chunk → 16, pad
 def test_rglru_scan_sweep(S, W, chunk):
     rng = np.random.default_rng(4)
     B = 2

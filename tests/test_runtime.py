@@ -128,6 +128,38 @@ def test_watchdog_flags_stragglers():
     assert wd.observe(0.5)
 
 
+# -- persistent compile cache -----------------------------------------------
+
+@pytest.mark.parametrize("outside", [False, True])
+def test_compile_cache_goes_where_the_environment_says(monkeypatch, tmp_path,
+                                                       outside):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and no directory is set in code;
+    without it the cache sits at the fixed in-checkout path. Every program
+    is cached either way. The config is restored before anything compiles,
+    so the suite itself never writes a cache."""
+    from repro.runtime import platform
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    if outside:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        used = platform.use_compile_cache()
+        now = {k: getattr(jax.config, k) for k in keys}
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    expect = str(tmp_path) if outside else os.path.join(root, ".jax_cache")
+    assert used == expect
+    assert now["jax_compilation_cache_dir"] == \
+        (saved["jax_compilation_cache_dir"] if outside else expect)
+    assert now["jax_persistent_cache_min_compile_time_secs"] == 0.0
+
+
 # -- data pipeline ----------------------------------------------------------
 
 def test_data_deterministic_and_resumable():

@@ -15,10 +15,15 @@ def _random_instance(rng, M=None, N=None, feasible=True):
     cost = rng.random((M, N)) * 10
     allowed = rng.random((M, N)) < 0.8
     if feasible:
-        allowed[np.arange(M), rng.integers(0, N, M)] = True
+        forced = rng.integers(0, N, M)
+        allowed[np.arange(M), forced] = True
     cap = rng.integers(1, max(M // max(N - 1, 1), 2), N)
     while feasible and cap.sum() < M:
         cap[rng.integers(0, N)] += 1
+    if feasible:
+        # Every row fits its forced column: a feasible assignment exists
+        # (enough capacity in total is not enough when rows have one arc).
+        cap = np.maximum(cap, np.bincount(forced, minlength=N))
     return cost, allowed, cap
 
 
