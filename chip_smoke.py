@@ -26,9 +26,12 @@ base rate's ~3,700 arrivals (bucket 4096).
 Checks, per phase: nothing shed; every placement within the free capacity
 the engine offered and on an arc the Eq-11 mask allows; the Sinkhorn
 implementation that ran, read from the ``round.sinkhorn/*`` counters
-(the compiled Pallas kernel in A and B); at least one round of
-``--min-rows`` job rows. A and B also re-solve every recorded round with the
-exact ``flow`` solver and require the served objective within 2 % of it.
+(the compiled Pallas kernel in A and B); at least one round of ``MIN_ROWS``
+job rows. A and B also re-solve every recorded round with the exact ``flow``
+solver and require the served objective within 2 % of it, and the Sinkhorn
+plan's own fractional objective within ``PLAN_GAP_LIMIT``; a cost-blind
+plan on the largest round must miss that limit, so the check can fail.
+Deadline misses and the host's SSP repairs are printed.
 
 ``--chips 4`` runs only the multi-chip path: an 8-cell plan of
 ``waterwise[backend=fused]`` cells at the same rate and sizes through
@@ -59,18 +62,20 @@ from repro.sim.engine import EventSimulator, SimConfig  # noqa: E402
 from repro.sim.trace import scale_capacity_for_utilization  # noqa: E402
 
 GAP_LIMIT = 0.02        # the integrality gap the solver tests pin
+# The Sinkhorn plan's own fractional objective, before the host rounds,
+# repairs and polishes it, must lie this close to the flow optimum. The
+# served objective cannot show a broken kernel: the host's rounding and
+# polish land within GAP_LIMIT from a cost-blind plan too. A cost-blind
+# plan's fractional objective lands tens of percent off, and each run
+# checks that on its largest round.
+PLAN_GAP_LIMIT = 0.05
+MIN_ROWS = 2048         # job rows the largest round of each phase reaches
 SINKHORN_MODES = ("pallas", "pallas_interpret", "xla")
 
-# The policy of each phase. ``{reserve}`` is the slack (s) a job must keep
-# to be deferred (A) or held (B, C): the policies' defaults (120 s, 240 s)
-# assume 30 s rounds, and a job deferred with less slack than one more
-# round period plus its transfer misses its deadline.
 PHASES = {
-    "A": "waterwise[backend=fused,record_windows=true,"
-         "defer_slack_s={reserve}]",
-    "B": "waterwise-forecast[backend=fused,record_windows=true,"
-         "guard_s={reserve}]",
-    "C": "waterwise-forecast[backend=fused,warm=true,guard_s={reserve}]",
+    "A": "waterwise[backend=fused,record_windows=true]",
+    "B": "waterwise-forecast[backend=fused,record_windows=true]",
+    "C": "waterwise-forecast[backend=fused,warm=true]",
 }
 
 
@@ -98,11 +103,6 @@ class Traffic:
     @property
     def end_s(self) -> float:
         return self.start_s + self.rounds * self.window_s
-
-    def spec(self, phase: str) -> str:
-        """Phase ``phase``'s policy spec, its slack reserve two round
-        periods."""
-        return PHASES[phase].format(reserve=2 * self.window_s)
 
     def source(self, num_regions: int) -> PoissonBurstArrivals:
         return PoissonBurstArrivals(
@@ -182,11 +182,24 @@ def _sinkhorn_counts() -> dict:
     return {m: counter_value(f"round.sinkhorn/{m}") for m in SINKHORN_MODES}
 
 
-def flow_gaps(sched) -> list:
-    """Re-solve every recorded round with the exact ``flow`` solver; check
-    the served assignment is feasible on that instance and return the
-    served objective's relative gap to the optimum, per round."""
-    gaps = []
+def _gap(objective: float, optimum: float) -> float:
+    return (objective - optimum) / max(abs(optimum), 1e-9)
+
+
+def flow_gaps(sched) -> dict:
+    """Re-solve every recorded round with the exact ``flow`` solver, check
+    the served assignment is feasible on that instance, and return, per
+    round, the relative gap to the optimum of the served objective (after
+    the host's rounding, repair and polish) and of the Sinkhorn plan's own
+    fractional objective (before them).
+
+    ``control`` holds a cost-blind plan, uniform over each job's allowed
+    arcs, on the largest recorded round: its fractional gap, which the plan
+    check must be able to see, and its served gap through the same host
+    rounding, repair and polish."""
+    from repro.core.solvers import jax_solver
+    served, plan = [], []
+    largest = None
     for w, ref in zip(sched.recorded, sched.replay_recorded(backend="flow")):
         assign = w["assign"]
         _require(ref.feasible, "flow found a recorded round infeasible")
@@ -198,9 +211,24 @@ def flow_gaps(sched) -> list:
         if not w["soften"]:
             _require(bool(w["allowed"][np.arange(len(assign)), assign].all()),
                      "a recorded round uses a masked arc")
-        gaps.append((w["objective"] - ref.objective)
-                    / max(abs(ref.objective), 1e-9))
-    return gaps
+        served.append(_gap(w["objective"], ref.objective))
+        plan.append(_gap(w["plan_objective"], ref.objective))
+        if not w["soften"] and (largest is None
+                                or len(assign) > len(largest[0]["assign"])):
+            largest = (w, ref)
+    _require(largest is not None, "no hard round to run the control on")
+    w, ref = largest
+    mask = w["allowed"].astype(bool)
+    blind = mask / mask.sum(axis=1, keepdims=True)
+    scale = max(float(np.abs(w["cost"][mask]).max()), 1e-9)
+    cn = np.where(mask, w["cost"] / scale, jax_solver.BIG)
+    res = jax_solver._finalize(blind, cn, w["cost"], mask,
+                               w["capacity"].astype(np.int64), False, None,
+                               None)
+    control = dict(rows=len(w["assign"]),
+                   plan_gap=_gap(res.plan_objective, ref.objective),
+                   flow_gap=_gap(res.objective, ref.objective))
+    return dict(served=served, plan=plan, control=control)
 
 
 def run_phase(name: str, spec: str, traffic: Traffic, *, kernel: str,
@@ -226,6 +254,7 @@ def run_phase(name: str, spec: str, traffic: Traffic, *, kernel: str,
     setup_s = time.perf_counter() - t0
 
     before = _sinkhorn_counts()
+    repairs0 = counter_value("solver.ssp_repair")
     compile0, hits0 = clock.seconds, clock.cache_hits
     t1 = time.perf_counter()
     rep = loop.run(traffic.end_s)
@@ -237,6 +266,7 @@ def run_phase(name: str, spec: str, traffic: Traffic, *, kernel: str,
         compile_s=clock.seconds - compile0,
         cache_hits=clock.cache_hits - hits0, solves=len(sched.rows),
         max_rows=max(sched.rows, default=0), sinkhorn=ran,
+        ssp_repairs=counter_value("solver.ssp_repair") - repairs0,
         placed=rep.placed, shed=rep.shed,
         deadline_misses=rep.deadline_misses, carbon_kg=rep.carbon_kg,
         water_kl=rep.water_kl, p99_round_ms=rep.p99_round_ms)
@@ -262,12 +292,22 @@ def run_phase(name: str, spec: str, traffic: Traffic, *, kernel: str,
              f"never {', '.join(forbid)}")
     if reference:
         gaps = flow_gaps(sched)
-        _require(bool(gaps),
-                 f"phase {name}: no recorded round to check against flow")
-        out.update(flow_rounds=len(gaps), max_flow_gap=max(gaps))
-        _require(max(gaps) <= GAP_LIMIT,
-                 f"phase {name}: objective gap to flow {max(gaps):.4%} "
-                 f"over {GAP_LIMIT:.0%}")
+        served, plan = max(gaps["served"]), max(map(abs, gaps["plan"]))
+        control = gaps["control"]
+        out.update(flow_rounds=len(gaps["served"]), max_flow_gap=served,
+                   max_plan_gap=plan, control_rows=control["rows"],
+                   control_plan_gap=control["plan_gap"],
+                   control_flow_gap=control["flow_gap"])
+        _require(served <= GAP_LIMIT,
+                 f"phase {name}: objective gap to flow {served:.4%} over "
+                 f"{GAP_LIMIT:.0%}")
+        _require(plan <= PLAN_GAP_LIMIT,
+                 f"phase {name}: the Sinkhorn plan is {plan:.4%} from the "
+                 f"flow optimum, over {PLAN_GAP_LIMIT:.0%}")
+        _require(abs(control["plan_gap"]) > PLAN_GAP_LIMIT,
+                 f"phase {name}: a cost-blind plan is only "
+                 f"{control['plan_gap']:.4%} from the optimum; the plan "
+                 f"check cannot tell it from the kernel's")
     return out
 
 
@@ -372,9 +412,6 @@ def main(argv=None) -> int:
                     help="4: run only the device-executor path on 4 chips")
     ap.add_argument("--rounds", type=int, default=Traffic.rounds,
                     help="served rounds per phase")
-    ap.add_argument("--min-rows", type=int, default=2048,
-                    help="job rows the largest round of each phase must "
-                         "reach")
     args = ap.parse_args(argv)
 
     import jax
@@ -402,10 +439,11 @@ def main(argv=None) -> int:
             for name in PHASES:
                 warm = name == "C"
                 res = run_phase(
-                    name, traffic.spec(name), traffic, kernel="xla" if warm else "pallas",
+                    name, PHASES[name], traffic,
+                    kernel="xla" if warm else "pallas",
                     forbid=("pallas_interpret",) if warm
                     else ("pallas_interpret", "xla"),
-                    min_rows=args.min_rows, reference=not warm, clock=clock)
+                    min_rows=MIN_ROWS, reference=not warm, clock=clock)
                 results[name] = res
                 print(f"phase {name}: {_fmt(res)}", flush=True)
             for key in ("carbon_kg", "water_kl"):

@@ -225,7 +225,7 @@ _assignment_program = functools.partial(jax.jit, static_argnames=(
     "interpret"))(_assignment_body)
 
 
-@solvers.register("fused")
+@solvers.register("fused", on_device=True)
 def fused_solve(cost: np.ndarray, allowed: np.ndarray, capacity: np.ndarray,
                 *, soften: bool = False,
                 overrun: Optional[np.ndarray] = None,

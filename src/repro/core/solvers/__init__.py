@@ -46,6 +46,9 @@ class SolveResult:
     solve_time_s: float
     penalties: np.ndarray       # [M] tolerance-overrun P value on chosen arc
     backend: str
+    # Sinkhorn backends: the transport plan's own fractional objective
+    # (each job's row normalised to one), before any rounding on the host.
+    plan_objective: Optional[float] = None
 
     @property
     def feasible(self) -> bool:
@@ -79,11 +82,16 @@ def _timed(fn: Callable[[], SolveResult],
 
 
 _REGISTRY: Dict[str, Callable] = {}
+_ON_DEVICE: set = set()         # backends that dispatch JAX work
 
 
-def register(name: str):
+def register(name: str, *, on_device: bool = False):
+    """Register backend ``name``; ``on_device`` marks one that dispatches
+    JAX work (and so needs the process that holds the accelerator)."""
     def deco(fn):
         _REGISTRY[name] = fn
+        if on_device:
+            _ON_DEVICE.add(name)
         return fn
     return deco
 
@@ -99,6 +107,12 @@ def get_solver(name: str) -> Callable:
         raise KeyError(f"solver backend {name!r} unavailable; "
                        f"have {sorted(_REGISTRY)}")
     return _REGISTRY[name]
+
+
+def on_device(name: str) -> bool:
+    """True when solver backend ``name`` dispatches JAX work."""
+    get_solver(name)
+    return name in _ON_DEVICE
 
 
 def available_backends() -> list:

@@ -323,12 +323,14 @@ def _finalize(X, Cn, c_eff, mask, cap, soften, overrun, tol):
     """Round the (real-row) plan to an integral vertex + polish + price."""
     M = Cn.shape[0]
     X = X / np.maximum(X.sum(axis=1, keepdims=True), 1e-30)
+    plan_obj = float(np.where(mask, X * c_eff, 0.0).sum())
     assign = _round_to_vertex(X, Cn, mask, cap)
     if (assign < 0).any():
         # Greedy rounding stranded a job (capacity-tight instance): repair
         # with the exact successive-shortest-path solver on the same
         # normalized costs. Only genuinely infeasible instances survive this.
         from repro.core.solvers import flow_solver
+        obs.counter("solver.ssp_repair")
         assign = flow_solver._ssp_assign(Cn, mask, cap)
     if (assign >= 0).all():
         assign = _improve_2swap(assign, Cn, mask, cap)
@@ -343,10 +345,11 @@ def _finalize(X, Cn, c_eff, mask, cap, soften, overrun, tol):
         penalties = excess[np.arange(M), assign]
     return solvers.SolveResult(assign=assign, objective=obj,
                                status="rounded", solve_time_s=0.0,
-                               penalties=penalties, backend="jax")
+                               penalties=penalties, backend="jax",
+                               plan_objective=plan_obj)
 
 
-@solvers.register("jax")
+@solvers.register("jax", on_device=True)
 def solve(cost: np.ndarray, allowed: np.ndarray, capacity: np.ndarray, *,
           soften: bool = False, overrun: Optional[np.ndarray] = None,
           tol: Optional[np.ndarray] = None, sigma: float = 10.0,

@@ -51,12 +51,6 @@ from repro.runtime import platform as runtime_platform
 from repro.spec import (Param, parse_raw, params_from_signature,
                         unknown_name_error, validate_params)
 
-# Solver backends that never dispatch JAX work, and forecasters fit in
-# numpy; every other backend or forecaster runs on the JAX device.
-_HOST_BACKENDS = frozenset({"flow", "scipy", "pulp"})
-_HOST_FORECASTERS = frozenset({"oracle", "seasonal-naive", "persistence"})
-
-
 def _policy_params(cell: Cell):
     """The cell's policy registry entry and its parameters, defaults filled
     in."""
@@ -69,18 +63,18 @@ def _policy_params(cell: Cell):
 
 
 def needs_device(cell: Cell) -> bool:
-    """True when running ``cell`` dispatches JAX work: a solver backend
-    other than the host-only ones, or a forecast-driven policy whose
-    forecaster is fit with JAX. Anything unclassifiable counts as needing
-    the device."""
+    """True when running ``cell`` dispatches JAX work: its solver backend,
+    or the forecaster of a forecast-driven policy, is registered
+    ``on_device``. Anything unclassifiable counts as needing the device."""
+    from repro import forecast
     try:
         entry, params = _policy_params(cell)
+        if "backend" in params and solvers.on_device(params["backend"]):
+            return True
+        return bool(entry.forecast_driven
+                    and forecast.on_device(params["forecaster"]))
     except Exception:                       # noqa: BLE001 — conservative
         return True
-    if "backend" in params and params["backend"] not in _HOST_BACKENDS:
-        return True
-    return bool(entry.forecast_driven
-                and params.get("forecaster") not in _HOST_FORECASTERS)
 
 
 def _device_cells_on_tpu(cells: Sequence[Cell]) -> List[Cell]:

@@ -23,7 +23,8 @@ from repro.forecast.backtest import (backtest, backtest_telemetry, mape,
 from repro.forecast.base import (Forecast, Forecaster, Oracle, Persistence,
                                  Perturbed, SeasonalNaive,
                                  describe_forecasters, forecaster_schema,
-                                 list_forecasters, make_forecaster)
+                                 list_forecasters, make_forecaster,
+                                 on_device)
 from repro.forecast.holtwinters import HoltWinters
 from repro.forecast.learned import LearnedForecaster
 from repro.forecast.planner import DeferralQueue, TemporalPlan, \
@@ -33,6 +34,7 @@ __all__ = [
     "Forecast", "Forecaster", "Persistence", "SeasonalNaive", "Oracle",
     "Perturbed", "HoltWinters", "LearnedForecaster", "make_forecaster",
     "list_forecasters", "forecaster_schema", "describe_forecasters",
+    "on_device",
     "backtest", "backtest_telemetry", "mape", "pinball_loss",
     "DeferralQueue", "TemporalPlan", "build_temporal_plan",
 ]

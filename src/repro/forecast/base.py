@@ -107,6 +107,7 @@ class Forecaster:
 
     name = "base"
     description = ""
+    on_device = False       # fit or run with JAX (needs the accelerator)
 
     def fit(self, history: np.ndarray) -> "Forecaster":
         raise NotImplementedError
@@ -283,6 +284,16 @@ def make_forecaster(name: str, **kw) -> Forecaster:
     if name not in _MODELS:
         raise _spec.unknown_name_error("forecaster", name, sorted(_MODELS))
     return _MODELS[name](**kw)
+
+
+def on_device(name: str) -> bool:
+    """True when forecaster ``name`` is fit or run with JAX."""
+    if name == Oracle.name:
+        return Oracle.on_device
+    _ensure_models()
+    if name not in _MODELS:
+        raise _spec.unknown_name_error("forecaster", name, sorted(_MODELS))
+    return _MODELS[name].on_device
 
 
 def list_forecasters() -> list:

@@ -115,6 +115,7 @@ class HoltWinters(base.Forecaster):
     """Damped-trend seasonal Holt–Winters with grid-selected smoothing."""
 
     name = "holtwinters"
+    on_device = True
     description = ("damped-trend seasonal ETS(A,Ad,A) filter on "
                    "jax.lax.scan, grid-selected smoothing, jitted once "
                    "per padded history shape")

@@ -234,6 +234,7 @@ class LearnedForecaster(base.Forecaster):
     outputs (residual over seasonal-naive; zero-init == seasonal-naive)."""
 
     name = "learned"
+    on_device = True
     description = ("RG-LRU (Griffin) sequence head with q10/q50/q90 "
                    "outputs, trained on sliding telemetry windows as a "
                    "residual over seasonal-naive")

@@ -29,7 +29,8 @@ _HELP: Dict[str, str] = {
     "sigma": "soft-violation penalty σ (Eqs 12-13)",
     "backend": "solver backend (flow / jax / fused / scipy / pulp)",
     "defer_margin": "defer-arc price margin over the trailing-mean cost",
-    "defer_slack_s": "min remaining TOL budget (s) to offer the defer arc",
+    "defer_slack_s": "min remaining TOL budget (s) to offer the defer arc "
+                     "(at least two engine round periods)",
     "record_windows": "record every solved window for offline batched replay",
     "forecaster": "forecast model (holtwinters / seasonal-naive / "
                   "persistence / learned / oracle)",
@@ -38,7 +39,8 @@ _HELP: Dict[str, str] = {
     "risk": "shade future slots toward the upper quantile band by this "
             "fraction",
     "defer_eps": "per-slot tie-break cost — deferral must earn its delay",
-    "guard_s": "tolerance budget reserve forcing early release of held jobs",
+    "guard_s": "tolerance budget reserve forcing early release of held jobs "
+               "(at least two engine round periods)",
     "warmup_hours": "telemetry archive hours used to warm-start the "
                     "forecaster (0 = cold start)",
     "forecast_bias": "multiplicative forecast error injection (1.0 = off)",

@@ -217,7 +217,7 @@ class SnapshotPricer(Pricer):
         # core.slack and the temporal planner — bit-identical to the former
         # per-job method loop; this runs every scheduling round.
         slack_left = problem.slack_budget(jobs, now_s)
-        can_wait = slack_left > self.defer_slack_s
+        can_wait = slack_left > pipe.reserve_s(self.defer_slack_s)
         return PricedPlan(
             cost=np.concatenate([cost, defer_cost[:, None]], axis=1),
             allowed=np.concatenate([inst.allowed, can_wait[:, None]], axis=1),
@@ -420,7 +420,7 @@ class ForecastPricer(Pricer):
                 inst, now_s, ci, ewif, wue, snap["pue"], snap["wsf"],
                 offsets, pipe.server, pipe.lam_co2, pipe.lam_h2o,
                 pipe.lam_ref, pipe.history.co2_ref, pipe.history.h2o_ref,
-                defer_eps=self.defer_eps, guard_s=self.guard_s,
+                defer_eps=self.defer_eps, guard_s=pipe.reserve_s(self.guard_s),
                 want_plan=pipe.record_windows, warm_start=self.warm_state)
             S = len(offsets)
             return PricedPlan(cost=cost, allowed=allowed, capacity=cap,
@@ -437,7 +437,7 @@ class ForecastPricer(Pricer):
             inst, now_s, ci, ewif, wue, snap["pue"], snap["wsf"], offsets,
             pipe.server, pipe.lam_co2, pipe.lam_h2o, pipe.lam_ref,
             pipe.history.co2_ref, pipe.history.h2o_ref,
-            defer_eps=self.defer_eps, guard_s=self.guard_s)
+            defer_eps=self.defer_eps, guard_s=pipe.reserve_s(self.guard_s))
         return PricedPlan(cost=plan.cost, allowed=plan.allowed,
                           capacity=plan.capacity,
                           overrun=np.tile(inst.overrun, (1, plan.num_slots)),
@@ -466,6 +466,8 @@ class ForecastPricer(Pricer):
 
 class DeferralPolicy:
     """Stage 3: what happens to jobs the solver decided not to run now."""
+
+    pipe: Optional["PolicyPipeline"] = None
 
     def bind(self, pipeline: "PolicyPipeline") -> None:
         self.pipe = pipeline
@@ -507,10 +509,16 @@ class QueueDeferral(DeferralPolicy):
 
     def __init__(self, guard_s: float = 240.0):
         from repro import forecast as fcast
+        self.guard_s = float(guard_s)
         self.queue = fcast.DeferralQueue(guard_s)
 
-    def admit(self, jobs, now_s, capacity=None):
+    def _partition(self, jobs, now_s):
+        if self.pipe is not None:
+            self.queue.guard_s = self.pipe.reserve_s(self.guard_s)
         return self.queue.partition(jobs, now_s)
+
+    def admit(self, jobs, now_s, capacity=None):
+        return self._partition(jobs, now_s)
 
     def hold(self, job, release_s, now_s):
         self.queue.hold(job, release_s, now_s)
@@ -587,7 +595,7 @@ class ReplanQueueDeferral(QueueDeferral):
                 else:
                     q.hold(j, release_s, now_s, held_at_s=held_at)
             self._carried.clear()
-        due, held = q.partition(jobs, now_s)
+        due, held = self._partition(jobs, now_s)
         if not held:
             return due, held
         # Re-plan only into *spare* seats: an added row must never displace
@@ -669,6 +677,9 @@ class PolicyPipeline:
         # (bucketed + vmapped Sinkhorn — one device dispatch per bucket).
         self.record_windows = record_windows
         self.recorded: List[dict] = []
+        # The engine's round period (``set_round_period``); 0 until an
+        # engine binds, and the configured slack reserves then hold as set.
+        self.round_s = 0.0
         self.pricer = pricer
         self.deferral = deferral or NextRoundDeferral()
         self.pricer.bind(self)
@@ -687,19 +698,32 @@ class PolicyPipeline:
         raise AttributeError(
             f"{type(self).__name__!r} object has no attribute {name!r}")
 
+    def set_round_period(self, round_s: float) -> None:
+        """The engine calls this with its round period (``window_s``)."""
+        self.round_s = float(round_s)
+
+    def reserve_s(self, configured: float) -> float:
+        """A slack reserve (the defer arc's ``defer_slack_s``, the hold
+        ``guard_s``) of at least two round periods. A job deferred or held
+        now is offered again only at a later round, up to one period late,
+        and must then keep a period of slack for its transfer and start."""
+        return max(float(configured), 2.0 * self.round_s)
+
     # -- offline replay ------------------------------------------------------
 
     def _record(self, cost, allowed, capacity, overrun, tol, soften,
                 res: solvers.SolveResult) -> None:
         """Capture the solved instance plus the decision it produced
-        (``assign``, ``objective``), so a replay can hold the served
-        decision against another backend on the same instance."""
+        (``assign``, ``objective``, and for Sinkhorn backends the transport
+        plan's fractional ``plan_objective``), so a replay can hold the
+        served decision against another backend on the same instance."""
         if self.record_windows:
             self.recorded.append(dict(
                 cost=np.array(cost), allowed=np.array(allowed),
                 capacity=np.array(capacity), overrun=np.array(overrun),
                 tol=np.array(tol), soften=bool(soften),
-                assign=np.array(res.assign), objective=float(res.objective)))
+                assign=np.array(res.assign), objective=float(res.objective),
+                plan_objective=res.plan_objective))
 
     def replay_recorded(self, backend: str = "jax") -> List[solvers.SolveResult]:
         """Re-solve every recorded scheduling window through the batched

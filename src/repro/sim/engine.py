@@ -140,6 +140,14 @@ def resolve_scheduler(scheduler, tele):
     return scheduler
 
 
+def _tell_round_period(scheduler, window_s: float) -> None:
+    """Give a scheduler that sizes its slack reserves by the round period
+    (``PolicyPipeline.set_round_period``) this engine's ``window_s``."""
+    hook = getattr(scheduler, "set_round_period", None)
+    if hook is not None:
+        hook(window_s)
+
+
 def resolve_capacity(payload, base: np.ndarray) -> np.ndarray:
     """Materialize a capacity-event payload against the base capacity."""
     if isinstance(payload, tuple) and len(payload) == 2 \
@@ -346,6 +354,7 @@ class EngineStepper:
                  hold_grid: bool = False):
         self.sim = sim
         self.scheduler = resolve_scheduler(scheduler, sim.tele)
+        _tell_round_period(self.scheduler, sim.cfg.window_s)
         self.hold_grid = hold_grid
         self.jobs: List[Job] = sorted(jobs, key=lambda j: j.submit_time_s)
         self._submit: List[float] = [j.submit_time_s for j in self.jobs]
@@ -653,6 +662,7 @@ class WindowedSimulator:
 
     def run(self, jobs: Sequence[Job], scheduler) -> Dict:
         scheduler = resolve_scheduler(scheduler, self.tele)
+        _tell_round_period(scheduler, self.cfg.window_s)
         jobs = sorted(jobs, key=lambda j: j.submit_time_s)
         cluster = Cluster(self.capacity)
         records: List[JobRecord] = []

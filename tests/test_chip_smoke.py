@@ -47,7 +47,7 @@ def _tiny(smoke):
 def test_phase_on_cpu(smoke, pallas_interpret, name, kernel, forbid):
     with smoke.CompileClock() as clock:
         traffic = _tiny(smoke)
-        res = smoke.run_phase(name, traffic.spec(name), traffic,
+        res = smoke.run_phase(name, smoke.PHASES[name], traffic,
                               kernel=kernel, forbid=forbid, min_rows=16,
                               reference=name != "C", clock=clock)
     assert 150 <= res["jobs"] <= 300
@@ -56,6 +56,21 @@ def test_phase_on_cpu(smoke, pallas_interpret, name, kernel, forbid):
     if name != "C":
         assert res["flow_rounds"] > 0
         assert res["max_flow_gap"] <= smoke.GAP_LIMIT
+        assert res["max_plan_gap"] <= smoke.PLAN_GAP_LIMIT
+        assert abs(res["control_plan_gap"]) > smoke.PLAN_GAP_LIMIT
+
+
+def test_phases_at_300s_rounds_keep_every_deadline(smoke):
+    """At the chip's 300 s rounds the policies as specified keep every job
+    inside its Eq-11 deadline: their slack reserves grow to two round
+    periods (``PolicyPipeline.reserve_s``)."""
+    traffic = smoke.Traffic(jobs_per_day=5e4, window_s=300.0, rounds=3)
+    with smoke.CompileClock() as clock:
+        for name in ("A", "B"):
+            res = smoke.run_phase(name, smoke.PHASES[name], traffic,
+                                  kernel="xla", forbid=("pallas",),
+                                  min_rows=16, reference=True, clock=clock)
+            assert res["deadline_misses"] == 0 and res["shed"] == 0
 
 
 def test_device_executor_phase_matches_serial(smoke):

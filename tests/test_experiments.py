@@ -342,10 +342,25 @@ def test_sweep_cli_exits_nonzero_when_a_cell_failed(crash_scenario,
     ("waterwise[backend=jax]", True),
     ("waterwise-forecast", True),                   # Holt-Winters in JAX
     ("waterwise-forecast[forecaster=seasonal-naive,backend=flow]", False),
+    ("waterwise-forecast[forecaster=learned,backend=flow]", True),
 ])
 def test_needs_device_classifies_policies(spec, device):
     cell = experiments.ExperimentPlan.build([CELL], [spec]).cells()[0]
     assert experiments.needs_device(cell) is device
+
+
+@pytest.mark.parametrize("kind,name,device", [
+    ("solver", "flow", False), ("solver", "scipy", False),
+    ("solver", "jax", True), ("solver", "fused", True),
+    ("forecaster", "oracle", False), ("forecaster", "persistence", False),
+    ("forecaster", "seasonal-naive", False),
+    ("forecaster", "holtwinters", True), ("forecaster", "learned", True),
+])
+def test_registries_declare_device_work(kind, name, device):
+    from repro import forecast
+    from repro.core import solvers
+    registry = solvers if kind == "solver" else forecast
+    assert registry.on_device(name) is device
 
 
 @pytest.fixture()
