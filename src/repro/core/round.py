@@ -247,20 +247,21 @@ def fused_solve(cost: np.ndarray, allowed: np.ndarray, capacity: np.ndarray,
         _, pad = _pad_rows(M)
         impl = sinkhorn_impl or sinkhorn_impl_default()
         interp = _interpret(impl, interpret)
-        arcs = np.stack([
-            _pad0(cost, pad),
-            _pad0(allowed.astype(np.float64), pad),
-            _pad0(overrun if overrun is not None else np.zeros((M, N)),
-                  pad)]).astype(np.float32)
-        tolv = np.stack([
-            _pad0(tol if tol is not None else np.zeros(M), pad),
-            _pad0(np.ones(M), pad)], axis=1).astype(np.float32)
-        Cn, X = _assignment_program(
-            jnp.asarray(arcs), jnp.asarray(tolv),
-            jnp.asarray(cap, jnp.float32),
-            soften=bool(soften), sigma=float(sigma), impl=impl,
-            eps_min=float(eps_min), interpret=interp)
-        Cn, X = jax.device_get((Cn, X))
+        with obs.span("solver.pack"):
+            arcs = np.stack([
+                _pad0(cost, pad),
+                _pad0(allowed.astype(np.float64), pad),
+                _pad0(overrun if overrun is not None else np.zeros((M, N)),
+                      pad)]).astype(np.float32)
+            tolv = np.stack([
+                _pad0(tol if tol is not None else np.zeros(M), pad),
+                _pad0(np.ones(M), pad)], axis=1).astype(np.float32)
+        with obs.span("solver.device"):
+            Cn, X = jax_solver.fetch(_assignment_program(
+                jnp.asarray(arcs), jnp.asarray(tolv),
+                jnp.asarray(cap, jnp.float32),
+                soften=bool(soften), sigma=float(sigma), impl=impl,
+                eps_min=float(eps_min), interpret=interp))
         _count_sinkhorn(impl, interp)
         if obs.enabled():
             bucket = M + 1 + pad
@@ -443,7 +444,7 @@ def fused_round_batch(requests, devices: int = 1) -> list:
             if compiles:
                 obs.counter("round.batch_compile", compiles)
             _count_sinkhorn(statics["impl"], statics["interpret"], B)
-            Cnb, Xb = jax.device_get(out)
+            Cnb, Xb = jax_solver.fetch(out)
             for b, i in enumerate(idxs):
                 r = requests[i]
                 M = r.cost.shape[0]
@@ -672,21 +673,24 @@ def fused_temporal_round(inst, now_s: float, ci, ewif, wue, pue, wsf,
         # One zero-initialized padded blob, filled in place: padding rows fall
         # out as zero-mass (validity 0) rows and the whole round uploads as two
         # contiguous copies (blob + rattrs).
-        W = 4 + 3 * S * N + 2 * N
-        blob = np.zeros((bucket - 1, W), np.float32)
-        for i, j in enumerate(jobs):
-            blob[i, 0] = j.energy_kwh
-            blob[i, 1] = j.exec_time_s
-            blob[i, 3] = 1.0
-        # One shared vectorized slack definition (critical-path aware for
-        # workflow tasks) — same expression the planner/pricers mask with.
-        blob[:M, 2] = problem.slack_budget(jobs, now_s)
-        # slot-major [ci | ewif | wue] per slot — [S, 3R] blocks flattened
-        blob[:M, 4:4 + 3 * S * N] = np.concatenate(
-            [ci, ewif, wue], axis=2).reshape(M, 3 * S * N)
-        blob[:M, 4 + 3 * S * N:4 + 3 * S * N + N] = inst.latency
-        blob[:M, 4 + 3 * S * N + N:] = inst.allowed
-        rattrs = np.stack([pue, wsf, ref_row, cap]).astype(np.float32)
+        with obs.span("solver.pack"):
+            W = 4 + 3 * S * N + 2 * N
+            blob = np.zeros((bucket - 1, W), np.float32)
+            for i, j in enumerate(jobs):
+                blob[i, 0] = j.energy_kwh
+                blob[i, 1] = j.exec_time_s
+                blob[i, 3] = 1.0
+            # One shared vectorized slack definition (critical-path aware
+            # for workflow tasks) — same expression the planner/pricers
+            # mask with.
+            blob[:M, 2] = problem.slack_budget(jobs, now_s)
+            # slot-major [ci | ewif | wue] per slot — [S, 3R] blocks
+            # flattened
+            blob[:M, 4:4 + 3 * S * N] = np.concatenate(
+                [ci, ewif, wue], axis=2).reshape(M, 3 * S * N)
+            blob[:M, 4 + 3 * S * N:4 + 3 * S * N + N] = inst.latency
+            blob[:M, 4 + 3 * S * N + N:] = inst.allowed
+            rattrs = np.stack([pue, wsf, ref_row, cap]).astype(np.float32)
         statics = dict(
             offsets=tuple(float(o) for o in slot_offsets),
             lam_co2=float(lam_co2), lam_h2o=float(lam_h2o),
@@ -706,14 +710,16 @@ def fused_temporal_round(inst, now_s: float, ci, ewif, wue, pue, wsf,
             # whole fixed budget available as the iteration cap (the cap
             # should never bind when the carry is any good).
             budget = jax_solver.SINKHORN_ITERS * jax_solver.SINKHORN_STAGES
-            out = _temporal_adaptive_program(
-                jnp.asarray(blob), jnp.asarray(rattrs), jnp.asarray(g0),
-                jnp.float32(warm_start.tol), **statics,
-                eps0=float(eps_min) if not cold else jax_solver.SINKHORN_EPS0,
-                eps_min=float(eps_min),
-                iters=budget if not cold else jax_solver.SINKHORN_ITERS,
-                anneal_stages=1 if not cold else jax_solver.SINKHORN_STAGES)
-            out = jax.device_get(out)
+            with obs.span("solver.device"):
+                out = jax_solver.fetch(_temporal_adaptive_program(
+                    jnp.asarray(blob), jnp.asarray(rattrs), jnp.asarray(g0),
+                    jnp.float32(warm_start.tol), **statics,
+                    eps0=(float(eps_min) if not cold
+                          else jax_solver.SINKHORN_EPS0),
+                    eps_min=float(eps_min),
+                    iters=budget if not cold else jax_solver.SINKHORN_ITERS,
+                    anneal_stages=(1 if not cold
+                                   else jax_solver.SINKHORN_STAGES)))
             warm_start.g = np.asarray(out[3], np.float32)
             used = int(out[4])
             (warm_start.cold_iters if cold
@@ -722,11 +728,11 @@ def fused_temporal_round(inst, now_s: float, ci, ewif, wue, pue, wsf,
                         else "solver.sinkhorn_iters_warm", float(used))
             t.set(warm=not cold, adaptive_iters=used)
         else:
-            out = _temporal_program(
-                jnp.asarray(blob), jnp.asarray(rattrs), **statics,
-                want_plan=bool(want_plan), impl=impl, eps_min=float(eps_min),
-                interpret=interp)
-            out = jax.device_get(out)
+            with obs.span("solver.device"):
+                out = jax_solver.fetch(_temporal_program(
+                    jnp.asarray(blob), jnp.asarray(rattrs), **statics,
+                    want_plan=bool(want_plan), impl=impl,
+                    eps_min=float(eps_min), interpret=interp))
         _count_sinkhorn(impl, interp)
         Cn = np.asarray(out[0][:M], np.float64)
         X = np.asarray(out[1][:M], np.float64)

@@ -32,6 +32,8 @@ import numpy as np
 import repro.obs as obs
 from repro.core import solvers
 
+obs.watch_jax()
+
 BIG = 1e4          # forbidden-arc cost after normalization to ~unit scale
 _NEG = -1e9        # log-domain mask value / zero-mass row marginal
 
@@ -212,6 +214,15 @@ def plan_from_duals(C, f, g, eps):
     return jnp.exp((f[:, None] + g[None, :] - C) / eps)
 
 
+def fetch(out):
+    """``jax.device_get`` of a solve's device outputs that adds the bytes
+    it copies to the host to counter ``solver.d2h_bytes``."""
+    out = jax.device_get(out)
+    obs.counter("solver.d2h_bytes", sum(
+        np.asarray(a).nbytes for a in jax.tree_util.tree_leaves(out)))
+    return out
+
+
 def _round_to_vertex(X: np.ndarray, cost: np.ndarray, mask: np.ndarray,
                      capacity: np.ndarray) -> np.ndarray:
     """Greedy confidence rounding + cheapest-feasible repair.
@@ -247,7 +258,9 @@ def _improve_2swap(assign: np.ndarray, cost: np.ndarray, mask: np.ndarray,
     """
     M, N = cost.shape
     used = np.bincount(assign[assign >= 0], minlength=N)
+    passes = moves = swaps = 0
     for _ in range(rounds):
+        passes += 1
         improved = False
         # Single moves into spare capacity.
         for m in range(M):
@@ -262,6 +275,7 @@ def _improve_2swap(assign: np.ndarray, cost: np.ndarray, mask: np.ndarray,
                 used[cur] -= 1
                 used[n] += 1
                 assign[m] = n
+                moves += 1
                 improved = True
         # Pairwise swaps (vectorized over the job×job delta matrix).
         a = assign
@@ -276,9 +290,11 @@ def _improve_2swap(assign: np.ndarray, cost: np.ndarray, mask: np.ndarray,
         m1, m2 = np.unravel_index(np.argmin(delta), delta.shape)
         if delta[m1, m2] < -1e-12:
             assign[m1], assign[m2] = assign[m2], assign[m1]
+            swaps += 1
             improved = True
         if not improved:
             break
+    obs.annotate(passes=passes, moves=moves, swaps=swaps)
     return assign
 
 
@@ -320,33 +336,40 @@ def _prepare(c_eff, mask, cap, pad_rows: int):
 
 
 def _finalize(X, Cn, c_eff, mask, cap, soften, overrun, tol):
-    """Round the (real-row) plan to an integral vertex + polish + price."""
-    M = Cn.shape[0]
-    X = X / np.maximum(X.sum(axis=1, keepdims=True), 1e-30)
-    plan_obj = float(np.where(mask, X * c_eff, 0.0).sum())
-    assign = _round_to_vertex(X, Cn, mask, cap)
-    if (assign < 0).any():
-        # Greedy rounding stranded a job (capacity-tight instance): repair
-        # with the exact successive-shortest-path solver on the same
-        # normalized costs. Only genuinely infeasible instances survive this.
-        from repro.core.solvers import flow_solver
-        obs.counter("solver.ssp_repair")
-        assign = flow_solver._ssp_assign(Cn, mask, cap)
-    if (assign >= 0).all():
-        assign = _improve_2swap(assign, Cn, mask, cap)
-    penalties = np.zeros(M)
-    if (assign < 0).any():
-        return solvers.SolveResult(assign=assign, objective=float("inf"),
-                                   status="infeasible", solve_time_s=0.0,
-                                   penalties=penalties, backend="jax")
-    obj = float(c_eff[np.arange(M), assign].sum())
-    if soften:
-        excess = np.maximum(overrun - tol[:, None], 0.0)
-        penalties = excess[np.arange(M), assign]
-    return solvers.SolveResult(assign=assign, objective=obj,
-                               status="rounded", solve_time_s=0.0,
-                               penalties=penalties, backend="jax",
-                               plan_objective=plan_obj)
+    """Round the (real-row) plan to an integral vertex + polish + price.
+    Span ``solver.finalize``, with children ``solver.round_vertex``,
+    ``solver.ssp_repair`` (only when taken) and ``solver.polish``."""
+    with obs.span("solver.finalize", jobs=Cn.shape[0]):
+        M = Cn.shape[0]
+        X = X / np.maximum(X.sum(axis=1, keepdims=True), 1e-30)
+        plan_obj = float(np.where(mask, X * c_eff, 0.0).sum())
+        with obs.span("solver.round_vertex"):
+            assign = _round_to_vertex(X, Cn, mask, cap)
+        if (assign < 0).any():
+            # Greedy rounding stranded a job (capacity-tight instance):
+            # repair with the exact successive-shortest-path solver on the
+            # same normalized costs. Only genuinely infeasible instances
+            # survive this.
+            from repro.core.solvers import flow_solver
+            obs.counter("solver.ssp_repair")
+            with obs.span("solver.ssp_repair"):
+                assign = flow_solver._ssp_assign(Cn, mask, cap)
+        if (assign >= 0).all():
+            with obs.span("solver.polish"):
+                assign = _improve_2swap(assign, Cn, mask, cap)
+        penalties = np.zeros(M)
+        if (assign < 0).any():
+            return solvers.SolveResult(assign=assign, objective=float("inf"),
+                                       status="infeasible", solve_time_s=0.0,
+                                       penalties=penalties, backend="jax")
+        obj = float(c_eff[np.arange(M), assign].sum())
+        if soften:
+            excess = np.maximum(overrun - tol[:, None], 0.0)
+            penalties = excess[np.arange(M), assign]
+        return solvers.SolveResult(assign=assign, objective=obj,
+                                   status="rounded", solve_time_s=0.0,
+                                   penalties=penalties, backend="jax",
+                                   plan_objective=plan_obj)
 
 
 @solvers.register("jax", on_device=True)
@@ -366,7 +389,7 @@ def solve(cost: np.ndarray, allowed: np.ndarray, capacity: np.ndarray, *,
         C, log_a, log_b, Cn = _prepare(c_eff, mask, cap, pad)
         f, g, eps = sinkhorn_log(jnp.asarray(C), jnp.asarray(log_a),
                                  jnp.asarray(log_b), eps_min=eps_min)
-        X = np.asarray(plan_from_duals(jnp.asarray(C), f, g, eps))[:M]
+        X = fetch(plan_from_duals(jnp.asarray(C), f, g, eps))[:M]
         if obs.enabled():
             # row-marginal residual: each real row targets mass 1/Σcap
             total = max(float(cap.sum()), 1e-9)
@@ -418,7 +441,7 @@ def solve_many(costs, alloweds, capacities, *, soften: bool = False,
             la = jnp.asarray(np.stack([it[2] for it in items]))
             lb = jnp.asarray(np.stack([it[3] for it in items]))
             fb, gb, eps = sinkhorn_log_batched(Cb, la, lb, eps_min=eps_min)
-            plans = np.asarray(jnp.exp(
+            plans = fetch(jnp.exp(
                 (fb[:, :, None] + gb[:, None, :] - Cb) / eps[:, None, None]))
             for it, X in zip(items, plans):
                 k, _, _, _, Cn, c_eff, mask, cap = it

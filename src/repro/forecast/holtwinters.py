@@ -27,7 +27,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+import repro.obs as obs
 from repro.forecast import base
+
+obs.watch_jax()
 
 # Candidate smoothing parameters (α, β, γ). A coarse grid is standard for
 # online refitting: the SSE surface is flat near the optimum and the filter
@@ -124,6 +127,12 @@ class HoltWinters(base.Forecaster):
         self.period = period
 
     def fit(self, history: np.ndarray) -> "HoltWinters":
+        """Refit on ``history`` [hours, columns]: span ``forecast.fit``."""
+        with obs.span("forecast.fit", hours=int(np.shape(history)[0]),
+                      columns=int(np.shape(history)[1])):
+            return self._fit(history)
+
+    def _fit(self, history: np.ndarray) -> "HoltWinters":
         y = np.asarray(history, np.float64)
         self._T = y.shape[0]
         self._last = y[-1]

@@ -98,5 +98,6 @@ def sinkhorn_iteration_pallas(C, g, log_a, log_b, *, eps: float,
         scratch_shapes=[pltpu.VMEM((1, Np), jnp.float32),
                         pltpu.VMEM((1, Np), jnp.float32)],
         interpret=interpret,
+        name="sinkhorn_iteration_pallas",
     )(Cp, gp[None], log_a[None].astype(jnp.float32), lbp[None])
     return f[0], g_new[0, :N]

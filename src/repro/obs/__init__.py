@@ -8,7 +8,18 @@ Three pillars:
   metrics back to the driver;
 * structured **span tracing** with nesting, exported as
   Chrome-trace-event JSONL (Perfetto / ``chrome://tracing``-loadable)
-  via :class:`~repro.obs.trace.TraceWriter`;
+  via :class:`~repro.obs.trace.TraceWriter`; every span carries its
+  identity (``sid``) and its parent's (``parent``), and while enabled
+  each span also enters a ``jax.profiler.TraceAnnotation`` of its name,
+  so a running profiler session records the program's spans on its own
+  clock beside the device ops;
+* runtime hooks while enabled: generation-2 garbage collections as
+  ``host.gc`` spans (every collection's seconds add to ``host.gc_s``),
+  and JAX's backend compiles as ``jax.compile`` spans; the ``jit/traces``
+  and ``jit/compiles`` counters are always live (:func:`watch_jax`);
+* :func:`session`: what the latest enabled session recorded (each
+  counter's increase, each span's count and seconds), readable after
+  :func:`disable`;
 * a reporting CLI (``python -m repro.obs.report``) rendering per-stage
   p50/p99 tables, per-region carbon/water/WUE series, and run diffs.
 
@@ -41,6 +52,8 @@ Instrumentation sites use::
 from __future__ import annotations
 
 import contextlib
+import gc
+import itertools
 import time
 import warnings
 from typing import Dict, List, Optional
@@ -53,7 +66,7 @@ from repro.obs.trace import (SIM_PID, TraceWriter, iter_spans, read_trace,
 __all__ = [
     "enabled", "enable", "disable", "capture", "span", "timed", "annotate",
     "counter", "gauge", "observe", "warn", "snapshot", "merge", "reset",
-    "counter_value", "tracer", "registry",
+    "counter_value", "tracer", "registry", "watch_jax", "session",
     "MetricsRegistry", "Histogram", "Counter", "Gauge", "merge_snapshots",
     "TraceWriter", "read_trace", "iter_spans", "validate_events",
     "HIST_BASE", "HIST_MAX_SAMPLES", "SIM_PID",
@@ -63,6 +76,15 @@ _REGISTRY = MetricsRegistry()
 _TRACER: Optional[TraceWriter] = None
 _ENABLED = False
 _STACK: List["_Span"] = []
+_SIDS = itertools.count(1)          # span identities, unique per process
+# ``jax.profiler.TraceAnnotation`` while enabled (imported by ``enable``),
+# else None: spans then enter no profiler annotation.
+_ANNOTATION = None
+_JAX_WATCHED = False
+# The latest enabled session's registry totals (:func:`_totals`) at its
+# enable, and at its disable (None while it is open).
+_SESSION_START: Optional[Dict] = None
+_SESSION_END: Optional[Dict] = None
 
 
 def enabled() -> bool:
@@ -78,22 +100,37 @@ def tracer() -> Optional[TraceWriter]:
 
 
 def enable(trace_path: Optional[str] = None) -> None:
-    """Turn collection on; if ``trace_path`` is given, also stream
-    Chrome-trace events there until :func:`disable`."""
-    global _ENABLED, _TRACER
+    """Turn collection on; if ``trace_path`` is given, also write
+    Chrome-trace events there until :func:`disable`. Registers the GC
+    hook and the JAX compile listener, and makes spans enter profiler
+    annotations. Starts a new :func:`session`."""
+    global _ENABLED, _TRACER, _ANNOTATION, _SESSION_START, _SESSION_END
+    _SESSION_START, _SESSION_END = _totals(), None
     _ENABLED = True
     if trace_path is not None:
         if _TRACER is not None:
             _TRACER.close()
         _TRACER = TraceWriter(trace_path)
+    from jax.profiler import TraceAnnotation
+    _ANNOTATION = TraceAnnotation
+    watch_jax()
+    _REGISTRY.counter("host.gc_s", 0.0)     # present, if zero, while enabled
+    if _gc_hook not in gc.callbacks:
+        gc.callbacks.append(_gc_hook)
 
 
 def disable() -> None:
     """Stop collection and close any open trace file. The metrics
     registry is kept (read it with :func:`snapshot`; clear with
-    :func:`reset`)."""
-    global _ENABLED, _TRACER
+    :func:`reset`). Closes the open :func:`session`."""
+    global _ENABLED, _TRACER, _ANNOTATION, _GC_SPAN, _SESSION_END
+    if _SESSION_START is not None and _SESSION_END is None:
+        _SESSION_END = _totals()
     _ENABLED = False
+    _ANNOTATION = None
+    if _gc_hook in gc.callbacks:
+        gc.callbacks.remove(_gc_hook)
+    _GC_SPAN = None
     if _TRACER is not None:
         _TRACER.close()
         _TRACER = None
@@ -134,6 +171,33 @@ def reset() -> None:
     _REGISTRY = MetricsRegistry()
 
 
+def _totals() -> Dict:
+    return {"counters": {k: c.value for k, c in _REGISTRY.counters.items()},
+            "spans": {k: (h.count, h.total)
+                      for k, h in _REGISTRY.hists.items()}}
+
+
+def session() -> Optional[Dict]:
+    """What the latest enabled session recorded, from its :func:`enable`
+    to its :func:`disable` (to now while it is open); None before any.
+    ``counters`` holds the increase of every counter present at its end
+    (``host.gc_s`` is, from ``enable`` on); ``spans`` holds
+    ``(count, seconds)`` of each span name, or :func:`observe` name,
+    recorded in it."""
+    if _SESSION_START is None:
+        return None
+    end = _SESSION_END or _totals()
+    c0, s0 = _SESSION_START["counters"], _SESSION_START["spans"]
+    spans = {}
+    for k, (n, total) in end["spans"].items():
+        n0, total0 = s0.get(k, (0, 0.0))
+        if n > n0:
+            spans[k] = (n - n0, total - total0)
+    return {"counters": {k: v - c0.get(k, 0.0)
+                         for k, v in end["counters"].items()},
+            "spans": spans}
+
+
 # ---------------------------------------------------------------------------
 # spans
 # ---------------------------------------------------------------------------
@@ -160,7 +224,8 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "args", "t0", "elapsed_s", "_measure_only")
+    __slots__ = ("name", "args", "t0", "elapsed_s", "_measure_only", "sid",
+                 "parent", "_ann")
 
     def __init__(self, name: str, args: Dict, measure_only: bool = False):
         self.name = name
@@ -168,6 +233,7 @@ class _Span:
         self.t0 = 0.0
         self.elapsed_s = 0.0
         self._measure_only = measure_only
+        self._ann = None
 
     def set(self, **args) -> None:
         self.args.update(args)
@@ -178,9 +244,14 @@ class _Span:
         return time.perf_counter() - self.t0
 
     def __enter__(self):
-        self.t0 = time.perf_counter()
         if not self._measure_only:
+            self.parent = _STACK[-1].sid if _STACK else None
+            self.sid = next(_SIDS)
             _STACK.append(self)
+            if _ANNOTATION is not None:
+                self._ann = _ANNOTATION(self.name)
+                self._ann.__enter__()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
@@ -188,13 +259,15 @@ class _Span:
         self.elapsed_s = t1 - self.t0
         if self._measure_only:
             return False
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         if _STACK and _STACK[-1] is self:
             _STACK.pop()
         _REGISTRY.observe(self.name, self.elapsed_s)
         if _TRACER is not None:
-            ts0 = (self.t0 - _TRACER._t0) * 1e6
-            _TRACER.complete(self.name, ts0, self.elapsed_s * 1e6,
-                             args=self.args or None)
+            self.args.update(sid=self.sid, parent=self.parent)
+            _TRACER.complete(self.name, (self.t0 - _TRACER._t0) * 1e6,
+                             self.elapsed_s * 1e6, args=self.args)
         return False
 
 
@@ -222,6 +295,69 @@ def annotate(**args) -> None:
     """Attach args to the innermost open (enabled) span, if any."""
     if _STACK:
         _STACK[-1].set(**args)
+
+
+# ---------------------------------------------------------------------------
+# runtime hooks: garbage collection and JAX compiles
+# ---------------------------------------------------------------------------
+
+_GC_SPAN: Optional[_Span] = None
+_GC_T0 = 0.0
+
+
+def _gc_hook(phase: str, info: Dict) -> None:
+    """``gc.callbacks`` hook, registered only while enabled: every
+    collection's seconds add to counter ``host.gc_s``; a generation-2
+    collection is also span ``host.gc``."""
+    global _GC_SPAN, _GC_T0
+    if phase == "start":
+        if info["generation"] == 2:
+            _GC_SPAN = _Span("host.gc", {"generation": 2})
+            _GC_SPAN.__enter__()
+        _GC_T0 = time.perf_counter()
+        return
+    _REGISTRY.counter("host.gc_s", time.perf_counter() - _GC_T0)
+    if _GC_SPAN is not None:
+        sp, _GC_SPAN = _GC_SPAN, None
+        sp.set(collected=info["collected"])
+        sp.__exit__(None, None, None)
+
+
+# JAX monitoring events -> the always-live counters they bump.
+_JAX_EVENTS = {"/jax/core/compile/jaxpr_trace_duration": "jit/traces",
+               "/jax/core/compile/backend_compile_duration": "jit/compiles"}
+
+
+def _on_jax_event(event: str, duration: float, **kwargs) -> None:
+    name = _JAX_EVENTS.get(event)
+    if name is None:
+        return
+    _REGISTRY.counter(name)
+    if name != "jit/compiles" or not _ENABLED:
+        return
+    # The event arrives as the compile ends: a span ending now that lasted
+    # ``duration``, child of the innermost open span.
+    _REGISTRY.observe("jax.compile", duration)
+    if _TRACER is not None:
+        t1 = time.perf_counter()
+        _TRACER.complete("jax.compile", (t1 - duration - _TRACER._t0) * 1e6,
+                         duration * 1e6, args=dict(
+                             fun=str(kwargs.get("fun_name", "")),
+                             sid=next(_SIDS),
+                             parent=_STACK[-1].sid if _STACK else None))
+
+
+def watch_jax() -> None:
+    """Count JAX's traces (``jit/traces``) and backend compiles, cache
+    loads included (``jit/compiles``), from here on; while enabled and
+    tracing, each compile is also span ``jax.compile``. Idempotent; the
+    modules that compile the solve path call it as they are imported."""
+    global _JAX_WATCHED
+    if _JAX_WATCHED:
+        return
+    import jax.monitoring
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_event)
+    _JAX_WATCHED = True
 
 
 # ---------------------------------------------------------------------------

@@ -33,9 +33,14 @@ _PHASES = {"X", "i", "C", "M"}
 
 
 class TraceWriter:
-    """Append-only trace-event writer. Not thread-safe by design — the
-    simulator is single-threaded and shard workers each get their own
-    process (and would write their own file)."""
+    """Append-only trace-event writer. Events are kept in memory and
+    written out by :meth:`close`, or whenever ``BUFFER_EVENTS`` have
+    gathered, so the hot path pays a list append and memory stays
+    bounded. Not thread-safe by design — the simulator is
+    single-threaded and shard workers each get their own process (and
+    would write their own file)."""
+
+    BUFFER_EVENTS = 65536
 
     def __init__(self, path: str) -> None:
         self.path = path
@@ -45,6 +50,7 @@ class TraceWriter:
         self._f.write("[\n")
         self._t0 = time.perf_counter()
         self._pid = os.getpid()
+        self._buf: List[Dict] = []
         self.events_written = 0
         self.metadata("process_name", {"name": "repro"})
         self.metadata("process_name", {"name": "simulated-time"}, pid=SIM_PID)
@@ -55,8 +61,17 @@ class TraceWriter:
 
     # -- emitters --------------------------------------------------------
     def _emit(self, ev: Dict) -> None:
-        self._f.write(json.dumps(ev, separators=(",", ":")) + ",\n")
+        self._buf.append(ev)
         self.events_written += 1
+        if len(self._buf) >= self.BUFFER_EVENTS:
+            self.flush()
+
+    def flush(self) -> None:
+        """Write the buffered events to the file."""
+        self._f.write("".join(json.dumps(ev, separators=(",", ":")) + ",\n"
+                              for ev in self._buf))
+        self._f.flush()
+        self._buf.clear()
 
     def complete(self, name: str, ts_us: float, dur_us: float,
                  args: Optional[Dict] = None, cat: str = "repro") -> None:
@@ -90,7 +105,7 @@ class TraceWriter:
 
     def close(self) -> None:
         if not self._f.closed:
-            self._f.flush()
+            self.flush()
             self._f.close()
 
 

@@ -407,7 +407,9 @@ class ForecastPricer(Pricer):
         with obs.span("policy.forecast"):
             self._refresh_forecast(now_s)
             offsets = np.arange(self.horizon_slots) * self.slot_s
-            ci, ewif, wue = self._slot_signal_tensors(jobs, now_s, offsets)
+            with obs.span("policy.signals", jobs=len(jobs)):
+                ci, ewif, wue = self._slot_signal_tensors(jobs, now_s,
+                                                          offsets)
         if pipe.backend == "fused":
             # Pricing, masking, Sinkhorn, and extraction run as ONE jitted
             # program; the plan comes back already hard-solved (bit-identical
@@ -756,6 +758,11 @@ class PolicyPipeline:
     def schedule(self, jobs: Sequence[problem.Job], now_s: float,
                  capacity: np.ndarray) -> Decision:
         jobs = list(jobs)                                    # J_all (line 3)
+        with obs.span("policy.schedule", pending=len(jobs)):
+            return self._schedule(jobs, now_s, capacity)
+
+    def _schedule(self, jobs: List[problem.Job], now_s: float,
+                  capacity: np.ndarray) -> Decision:
         if not jobs:
             return Decision([], np.zeros(0, np.int64), [], None, False)
 

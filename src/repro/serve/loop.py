@@ -104,7 +104,7 @@ class DecisionLoop:
         seconds the engine step took."""
         cfg = self.cfg
         arrivals = self.source.poll(t_k)
-        with obs.span("serve.round", boundary_s=t_k,
+        with obs.span("serve.round", round=self.rounds, boundary_s=t_k,
                       arrivals=len(arrivals)) as sp:
             self.admission.offer(arrivals, self.stepper.now)
             batch = self.admission.take(cfg.max_round_jobs)

@@ -479,12 +479,6 @@ class EngineStepper:
                            deferred=len(dec.deferred))
                     pending = list(dec.deferred)
                     rounds += 1
-                if obs.enabled():
-                    tr = obs.tracer()
-                    if tr is not None:
-                        tr.counter("engine/queue", {
-                            "pending": len(pending),
-                            "scheduled": len(dec.scheduled)})
             # Deadlock guard: pending jobs that no scheduler round can place
             # and no running job will ever release capacity for. A future
             # capacity event may still unblock them (outage restoration), and

@@ -11,6 +11,7 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 import repro.obs as obs
+from repro.core import solvers
 from repro.obs.metrics import (HIST_BASE, Histogram, MetricsRegistry,
                                bucket_bounds, bucket_index, merge_snapshots)
 
@@ -239,14 +240,16 @@ def test_degenerate_wan_warn_counter(monkeypatch):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.slow
-def test_records_bit_identical_obs_on_vs_off(tmp_path):
+@pytest.mark.parametrize("spec", ["waterwise[backend=jax]",
+                                  "waterwise[backend=fused]"])
+def test_records_bit_identical_obs_on_vs_off(tmp_path, spec):
     from repro.experiments.plan import Cell
     from repro.experiments.runner import run_cell
     from repro.experiments.scenario import parse_scenario
     from repro import policy
 
     cell = Cell(parse_scenario("diurnal[days=0.05,jobs_per_day=2000]"),
-                policy.as_spec("waterwise[backend=jax]"), 0)
+                policy.as_spec(spec), 0)
     assert not obs.enabled()
     off = run_cell(cell, return_result=True)
     with obs.capture(trace_path=str(tmp_path / "cell.trace.jsonl")):
@@ -260,6 +263,291 @@ def test_records_bit_identical_obs_on_vs_off(tmp_path):
         == [key(r) for r in on["_result"]["records"]]
     for col in ("carbon_kg", "water_kl", "violation_pct", "utilization"):
         assert off[col] == on[col]
+
+
+# ---------------------------------------------------------------------------
+# spans inside the solve, their identity, the runtime hooks
+# ---------------------------------------------------------------------------
+
+def _spans(path):
+    events = obs.read_trace(str(path))
+    assert obs.validate_events(events) == []
+    return [e for e in events if e["ph"] == "X"]
+
+
+def _program_spans(path):
+    """The spans of the program's own sites (no GC or compile events)."""
+    return [e for e in _spans(path)
+            if e["name"] not in ("host.gc", "jax.compile")]
+
+
+def _children(spans, parent):
+    return [e["name"] for e in spans
+            if e["args"]["parent"] == parent["args"]["sid"]]
+
+
+def _assert_solve_tree(spans, root_name):
+    sids = [e["args"]["sid"] for e in spans]
+    assert len(set(sids)) == len(sids)
+    by = {e["name"]: e for e in spans}
+    root = by[root_name]
+    assert root["args"]["parent"] is None
+    assert _children(spans, root) == ["solver.pack", "solver.device",
+                                      "solver.finalize"]
+    assert _children(spans, by["solver.finalize"]) == [
+        "solver.round_vertex", "solver.polish"]
+    polish = by["solver.polish"]["args"]
+    assert polish["passes"] >= 1 and polish["moves"] >= 0 \
+        and polish["swaps"] >= 0
+    for e in spans:                        # children inside their parent
+        if e["args"]["parent"] is not None:
+            p = next(q for q in spans
+                     if q["args"]["sid"] == e["args"]["parent"])
+            assert p["ts"] <= e["ts"] + 1e-3
+            assert e["ts"] + e["dur"] <= p["ts"] + p["dur"] + 1e-3
+
+
+def test_fused_solve_spans_nest_with_identity(tmp_path):
+    from repro.core import round as fused_round
+    rng = np.random.default_rng(0)
+    cost = rng.random((20, 6))
+    with obs.capture(trace_path=str(tmp_path / "t.jsonl")):
+        res = fused_round.fused_solve(cost, np.ones((20, 6), bool),
+                                      np.full(6, 10), sinkhorn_impl="pallas",
+                                      interpret=True)
+    assert res.feasible
+    _assert_solve_tree(_program_spans(tmp_path / "t.jsonl"), "solver.solve")
+
+
+def _temporal_case(M, S=8, R=5):
+    from repro.core import footprint, problem, telemetry
+    tele = telemetry.generate(days=1, seed=0)
+    rng = np.random.default_rng(M)
+    jobs = [problem.Job(job_id=i, home_region=i % R, submit_time_s=0.0,
+                        exec_time_s=600.0 + 10 * i, energy_kwh=0.05,
+                        tolerance=4.0) for i in range(M)]
+    cap = np.full(R, max(2, M // R + 1))
+    snap = tele.at(0.0)
+    server = footprint.m5_metal()
+    inst = problem.build(jobs, tele, 0.0, cap, server, snap=snap)
+    shape = (M, S, R)
+    return (inst, 0.0, rng.random(shape) * 300 + 50,
+            rng.random(shape) * 2 + 0.5, rng.random(shape) + 0.2,
+            snap["pue"], snap["wsf"], np.arange(S) * 1800.0, server, 0.5,
+            0.5)
+
+
+def test_fused_temporal_round_spans_nest_with_identity(tmp_path):
+    from repro.core import round as fused_round
+    with obs.capture(trace_path=str(tmp_path / "t.jsonl")):
+        *_, res = fused_round.fused_temporal_round(
+            *_temporal_case(12), sinkhorn_impl="pallas", interpret=True)
+    assert res.feasible
+    _assert_solve_tree(_program_spans(tmp_path / "t.jsonl"),
+                       "solver.fused_round")
+
+
+def test_ssp_repair_span_only_when_taken(tmp_path, monkeypatch):
+    from repro.core.solvers import jax_solver
+    rng = np.random.default_rng(1)
+    cost, allowed, cap = rng.random((15, 5)), np.ones((15, 5), bool), \
+        np.full(5, 4)
+    monkeypatch.setattr(jax_solver, "_round_to_vertex",
+                        lambda X, c, m, k: np.full(len(X), -1))
+    with obs.capture(trace_path=str(tmp_path / "t.jsonl")):
+        res = solvers.solve(cost, allowed, cap, backend="fused")
+    assert res.feasible
+    spans = _program_spans(tmp_path / "t.jsonl")
+    fin = next(e for e in spans if e["name"] == "solver.finalize")
+    assert _children(spans, fin) == ["solver.round_vertex",
+                                     "solver.ssp_repair", "solver.polish"]
+
+
+def _d2h(fn):
+    before = obs.counter_value("solver.d2h_bytes")
+    fn()
+    return obs.counter_value("solver.d2h_bytes") - before
+
+
+@pytest.mark.parametrize("M,N", [(20, 6), (300, 6), (511, 40), (40, 5)])
+def test_d2h_bytes_per_fused_solve(M, N):
+    """Counted with obs off: the normalized costs and the plan, each
+    (bucket - 1) x N float32 rows."""
+    from repro.core.round import _pad_rows
+    rng = np.random.default_rng(M)
+    cap = np.full(N, M // N + 2)
+    got = _d2h(lambda: solvers.solve(rng.random((M, N)),
+                                     np.ones((M, N), bool), cap,
+                                     backend="fused"))
+    bucket, _ = _pad_rows(M)
+    assert got == 2 * (bucket - 1) * N * 4
+
+
+@pytest.mark.parametrize("warm", [False, True])
+def test_d2h_bytes_per_fused_temporal_round(warm):
+    from repro.core import round as fused_round
+    from repro.core.round import _pad_rows
+    M, cols = 12, 8 * 5
+    ws = fused_round.SinkhornWarmStart() if warm else None
+    got = _d2h(lambda: fused_round.fused_temporal_round(
+        *_temporal_case(M), warm_start=ws))
+    bucket, _ = _pad_rows(M)
+    # Cn and X, the 4-byte scale; the warm path also the potentials g and
+    # the int32 iteration count.
+    want = 2 * (bucket - 1) * cols * 4 + 4 + (cols * 4 + 4 if warm else 0)
+    assert got == want
+
+
+def test_d2h_bytes_of_the_jax_backend_plan():
+    from repro.core.solvers.jax_solver import bucket_for
+    rng = np.random.default_rng(2)
+    got = _d2h(lambda: solvers.solve(rng.random((9, 5)),
+                                     np.ones((9, 5), bool), np.full(5, 3),
+                                     backend="jax"))
+    assert got == bucket_for(10) * 5 * 4     # the padded plan, dummy row in
+
+
+def test_fetch_counts_what_it_copies():
+    import jax.numpy as jnp
+    from repro.core.solvers import jax_solver
+    out = {}
+    got = _d2h(lambda: out.update(v=jax_solver.fetch(
+        (jnp.ones((3, 4)), jnp.zeros(5, jnp.int32), jnp.float32(2.0)))))
+    assert got == sum(np.asarray(a).nbytes for a in out["v"]) == 48 + 20 + 4
+    assert all(isinstance(a, np.ndarray) for a in out["v"])
+
+
+def test_gc_hook_exists_only_while_enabled(tmp_path):
+    import gc
+    assert obs._gc_hook not in gc.callbacks
+    with obs.capture(trace_path=str(tmp_path / "t.jsonl")):
+        assert obs._gc_hook in gc.callbacks
+        with obs.span("outer"):
+            gc.collect()
+        gc_s = obs.counter_value("host.gc_s")
+    assert obs._gc_hook not in gc.callbacks
+    assert gc_s > 0
+    spans = _spans(tmp_path / "t.jsonl")
+    outer = next(e for e in spans if e["name"] == "outer")
+    assert "host.gc" in _children(spans, outer)
+    before = obs.counter_value("host.gc_s")
+    gc.collect()
+    assert obs.counter_value("host.gc_s") == before
+
+
+def test_fresh_shape_emits_one_compile_span(tmp_path):
+    import jax
+    f = jax.jit(lambda x: x * 2.0 + 1.0)
+    with obs.capture(trace_path=str(tmp_path / "t.jsonl")):
+        c0 = obs.counter_value("jit/compiles")
+        with obs.span("outer"):
+            f(np.ones(13, np.float32))
+        c1 = obs.counter_value("jit/compiles")
+        f(np.ones(13, np.float32))             # cached: nothing compiles
+        assert obs.counter_value("jit/compiles") == c1
+    assert c1 - c0 == 1
+    spans = _spans(tmp_path / "t.jsonl")
+    outer = next(e for e in spans if e["name"] == "outer")
+    compiles = [e for e in spans if e["name"] == "jax.compile"]
+    assert len(compiles) == 1
+    assert compiles[0]["args"]["parent"] == outer["args"]["sid"]
+    assert outer["ts"] <= compiles[0]["ts"] + 1e-3
+    # the counters stay live with obs off
+    before = obs.counter_value("jit/compiles")
+    f(np.ones(17, np.float32))
+    assert obs.counter_value("jit/compiles") == before + 1
+
+
+def test_trace_writer_keeps_events_until_its_buffer_fills(tmp_path,
+                                                          monkeypatch):
+    monkeypatch.setattr(obs.TraceWriter, "BUFFER_EVENTS", 5)
+    path = tmp_path / "t.jsonl"
+    with obs.capture(trace_path=str(path)):
+        for i in range(12):
+            with obs.span("step", i=i):
+                pass
+        written = obs.read_trace(str(path))
+        emitted = obs.tracer().events_written
+        assert len(written) % 5 == 0 and emitted - 5 < len(written) <= emitted
+    events = obs.read_trace(str(path))
+    assert obs.validate_events(events) == [] and len(events) == emitted
+    assert [e["args"]["i"] for e in events if e["ph"] == "X"] == list(
+        range(12))
+
+
+def test_engine_trace_has_no_queue_counter(tmp_path):
+    from repro.core import telemetry
+    from repro.sim import EventSimulator, borg_trace
+    from repro import policy
+    tele = telemetry.generate(days=1, seed=0)
+    jobs = borg_trace(days=0.02, seed=3, tolerance=0.5)
+    with obs.capture(trace_path=str(tmp_path / "t.jsonl")):
+        EventSimulator(tele, np.full(tele.num_regions, 40)).run(
+            jobs, policy.build("waterwise", tele))
+    events = obs.read_trace(str(tmp_path / "t.jsonl"))
+    assert any(e["name"] == "engine.round" for e in events)
+    assert not any(e["name"] == "engine/queue" for e in events)
+
+
+def test_session_records_the_latest_enabled_window(tmp_path):
+    obs.counter("t/session", 5)                # before: not counted
+    path = tmp_path / "t.jsonl"
+    with obs.capture(trace_path=str(path), fresh=False):
+        obs.counter("t/session", 2)
+        for _ in range(3):
+            with obs.span("t.outer"):
+                with obs.span("t.inner"):
+                    sum(range(100))
+        live = obs.session()
+    obs.counter("t/session", 100)              # after: not counted
+    s = obs.session()
+    assert s["counters"]["t/session"] == 2 and "host.gc_s" in s["counters"]
+    assert live["spans"]["t.outer"] == s["spans"]["t.outer"]
+    events = [e for e in obs.read_trace(str(path)) if e["ph"] == "X"]
+    for name in ("t.outer", "t.inner"):
+        n, seconds = s["spans"][name]
+        durs = [e["dur"] for e in events if e["name"] == name]
+        assert n == len(durs) == 3
+        assert seconds == pytest.approx(1e-6 * sum(durs), abs=1e-5)
+    with obs.capture(fresh=False):             # a new session starts empty
+        assert "t.outer" not in obs.session()["spans"]
+
+
+def test_served_round_nests_the_schedule_and_its_solves(tmp_path):
+    """Every solve span of a served round lies in a ``policy.schedule``
+    span, and every ``policy.schedule`` in a ``serve.round``: what the
+    benchmark's ``engine_self_ms`` and ``policy_self_ms`` subtract."""
+    from repro.core import telemetry
+    from repro.policy.pipeline import forecast_pipeline
+    from repro.serve import DecisionLoop, ReplayArrivals, ServeConfig
+    from repro.sim import borg_trace
+    from repro.sim.engine import EventSimulator, SimConfig
+    tele = telemetry.generate(days=1, seed=0)
+    jobs = borg_trace(days=0.02, seed=3, tolerance=4.0)
+    loop = DecisionLoop(
+        EventSimulator(tele, np.full(tele.num_regions, 40), SimConfig()),
+        forecast_pipeline(tele, forecaster="oracle", risk=0.0,
+                          defer_eps=1e-4, backend="fused"),
+        ReplayArrivals(jobs), ServeConfig(round_s=300.0,
+                                          queue_bound=1 << 30))
+    with obs.capture(trace_path=str(tmp_path / "t.jsonl")):
+        loop.run(0.02 * 86400.0, drain=False)   # rounds only, no drain
+    spans = _spans(tmp_path / "t.jsonl")
+    by_sid = {e["args"]["sid"]: e for e in spans}
+
+    def ancestors(e):
+        while e["args"]["parent"] is not None:
+            e = by_sid[e["args"]["parent"]]
+            yield e["name"]
+
+    solves = [e for e in spans
+              if e["name"] in ("solver.solve", "solver.fused_round")]
+    calls = [e for e in spans if e["name"] == "policy.schedule"]
+    assert solves and calls
+    assert all("policy.schedule" in ancestors(e) for e in solves)
+    assert all("serve.round" in ancestors(e) for e in calls)
+    rounds = [e["args"]["round"] for e in spans if e["name"] == "serve.round"]
+    assert rounds == sorted(rounds) and len(set(rounds)) == len(rounds)
 
 
 # ---------------------------------------------------------------------------
