@@ -13,6 +13,11 @@ of a run and restores it after.
   placing none.
 
 There is no exchange between chips in a one-chip cell to leave out.
+
+``late_dispatch`` is not among ``FAULTS``: it leaves every answer as the
+reference would give it and moves ``failed``, not ``correct``. It starts
+the first job placed at or after a decision instant at its deadline, so a
+job that could have run on time finishes late.
 """
 from __future__ import annotations
 
@@ -75,6 +80,22 @@ def unchanged_state():
         return pipeline.Decision([], np.zeros(0, np.int64), list(jobs), None,
                                  False)
     return _patch(pipeline.PolicyPipeline, "schedule", schedule)
+
+
+def late_dispatch(at_s: float):
+    from chipbench.harness import deadline_s
+    from repro.policy import pipeline
+    schedule = pipeline.PolicyPipeline.schedule
+    delayed = []
+
+    def delay_one(self, jobs, now_s, capacity):
+        dec = schedule(self, jobs, now_s, capacity)
+        if not delayed and now_s >= at_s and dec.scheduled:
+            job = dec.scheduled[0]
+            job.planned_start_s = deadline_s(job)
+            delayed.append(job)
+        return dec
+    return _patch(pipeline.PolicyPipeline, "schedule", delay_one)
 
 
 FAULTS = dict(cost_blind=cost_blind, altered_answer=altered_answer,

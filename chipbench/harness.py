@@ -287,6 +287,30 @@ def _tele_arrays(tele) -> dict:
 PHASE = 0.5
 
 
+def deadline_s(job) -> float:
+    """The last instant a job may finish: ``submit + (1 + tolerance) ·
+    exec``."""
+    return job.submit_time_s + (1.0 + job.tolerance) * job.exec_time_s
+
+
+def first_decision_s(submit_s: float, round_s: float) -> float:
+    """The engine loop instant at which a job submitted at ``submit_s`` is
+    first decided: the loop opens at ``round_s`` and steps ``round_s``
+    (``SimConfig(window_s=round_s)``), taking every job submitted at or
+    before its instant."""
+    return max(round_s, math.ceil(submit_s / round_s) * round_s)
+
+
+def late_on_arrival(job, round_s: float) -> bool:
+    """Whether a job's deadline has passed before its first decision could
+    start it: even started at that instant on its home region, where
+    transfer takes no time, it would finish late. Read from the job's own
+    fields and the loop's instants alone, never from what the program
+    decided."""
+    return (first_decision_s(job.submit_time_s, round_s) + job.exec_time_s
+            > deadline_s(job) + 1e-6)
+
+
 def run_cell(*, config: dict, traffic: dict, limits: dict, metrics: list,
              seed: int, seconds: float, traced: bool, t_start: float,
              device: dict) -> dict:
@@ -360,20 +384,24 @@ def run_cell(*, config: dict, traffic: dict, limits: dict, metrics: list,
         if v is not None:
             out_metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
 
-    late = sum(1 for job, _, _, finish in placed
-               if finish > job.submit_time_s
-               + (1.0 + job.tolerance) * job.exec_time_s + 1e-6)
+    # A job placed past its deadline is the program's failure, unless its
+    # deadline had passed before the loop could first decide it.
+    late = [job for job, _, _, finish in placed
+            if finish > deadline_s(job) + 1e-6]
+    unreachable = sum(late_on_arrival(job, round_s) for job in late)
     t_ref = time.perf_counter()
     checks, readings = compare(rec, window_calls, tele, config, round_s,
                                limits, int(traffic["reference_rounds"]),
                                seed, compiles)
     readings["reference_s"] = time.perf_counter() - t_ref
-    readings["late"] = late
+    readings["late"] = len(late)
+    readings["late_on_arrival"] = unreachable
     readings["stream_end"] = stream_end
     result = dict(correct=all(c["value"] <= c["limit"]
                               for c in checks.values()),
                   attempted=loop.admission.offered - offered0,
-                  failed=(loop.admission.shed - shed0) + late,
+                  failed=(loop.admission.shed - shed0)
+                  + len(late) - unreachable,
                   metrics=out_metrics, device=device)
     if traced:
         result["device"].update(busy_s=run.trace.busy_s,
