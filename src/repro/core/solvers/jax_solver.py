@@ -255,47 +255,95 @@ def _improve_2swap(assign: np.ndarray, cost: np.ndarray, mask: np.ndarray,
 
     Polishes the rounded vertex; with the Sinkhorn duals already near-optimal
     this usually closes the (small) remaining gap to the exact optimum.
+    Each pass makes every improving single move in row order, then the best
+    improving swap; both cost O(M·N), plus O(_SCAN_BLOCK·N) per move.
     """
-    M, N = cost.shape
+    N = cost.shape[1]
     used = np.bincount(assign[assign >= 0], minlength=N)
     passes = moves = swaps = 0
     for _ in range(rounds):
         passes += 1
-        improved = False
-        # Single moves into spare capacity.
-        for m in range(M):
-            if assign[m] < 0:
-                continue
-            cur = assign[m]
-            deltas = np.where(mask[m] & (used < capacity),
-                              cost[m] - cost[m, cur], np.inf)
-            deltas[cur] = np.inf
-            n = int(np.argmin(deltas))
-            if deltas[n] < -1e-12:
-                used[cur] -= 1
-                used[n] += 1
-                assign[m] = n
-                moves += 1
-                improved = True
-        # Pairwise swaps (vectorized over the job×job delta matrix).
-        a = assign
-        ok = a >= 0
-        cm = cost[np.arange(M), np.where(ok, a, 0)]
-        # delta of swapping m1<->m2: c[m1,a2]+c[m2,a1]-c[m1,a1]-c[m2,a2]
-        c_m1_a2 = np.where(mask[:, a] & ok[None, :], cost[:, a], np.inf)
-        delta = c_m1_a2 + c_m1_a2.T - cm[:, None] - cm[None, :]
-        delta[~ok] = np.inf
-        delta[:, ~ok] = np.inf
-        np.fill_diagonal(delta, np.inf)
-        m1, m2 = np.unravel_index(np.argmin(delta), delta.shape)
-        if delta[m1, m2] < -1e-12:
+        moved = _move_pass(assign, _gains(assign, cost, mask), used, capacity)
+        moves += moved
+        m1, m2, delta = _best_swap(assign, _gains(assign, cost, mask))
+        if delta < -1e-12:
             assign[m1], assign[m2] = assign[m2], assign[m1]
             swaps += 1
-            improved = True
-        if not improved:
+        elif not moved:
             break
     obs.annotate(passes=passes, moves=moves, swaps=swaps)
     return assign
+
+
+# Rows the single-move pass prices at once. A move that opens or fills a
+# column restarts the scan after the mover and discards the rest of its block.
+_SCAN_BLOCK = 256
+
+
+def _gains(assign, cost, mask):
+    """``G[m, q] = cost[m, q] - cost[m, assign[m]]``, the change in cost of
+    moving row m to column q; ``inf`` where the arc is masked, at the row's
+    own column and on unassigned rows."""
+    rows = np.arange(len(assign))
+    ok = assign >= 0
+    cur = np.where(ok, assign, 0)
+    G = np.where(mask & ok[:, None], cost - cost[rows, cur][:, None], np.inf)
+    G[rows, cur] = np.inf
+    return G
+
+
+def _move_pass(assign, G, used, capacity) -> int:
+    """In row order, move each row to its cheapest column with spare
+    capacity where that lowers the cost (the lowest column on ties),
+    updating ``assign`` and ``used``. The open columns change only at a move,
+    so rows are priced against them a block at a time; a move that opens or
+    fills a column restarts the scan after the mover. Returns the number of
+    moves."""
+    M = len(assign)
+    moves = m = 0
+    while m < M:
+        open_ = used < capacity
+        D = np.where(open_, G[m:m + _SCAN_BLOCK], np.inf)
+        n = D.argmin(axis=1)
+        start, m = m, m + len(n)
+        for h in np.flatnonzero(D[np.arange(len(n)), n] < -1e-12):
+            r, q = start + h, n[h]
+            used[assign[r]] -= 1
+            used[q] += 1
+            assign[r] = q
+            moves += 1
+            if not np.array_equal(used < capacity, open_):
+                m = r + 1
+                break
+    return moves
+
+
+def _best_swap(assign, G):
+    """The swap of two rows in different columns that lowers the cost most:
+    ``(m1, m2, delta)``. Swapping m1 (column p) with m2 (column q) changes
+    the cost by ``G[m1, q] + G[m2, p]``, so each column pair's best swap
+    pairs the row of p that gains most in q with the row of q that gains
+    most in p (the lowest row on ties); ``delta`` is ``inf`` where no swap
+    is allowed."""
+    N = G.shape[1]
+    best = np.full((N, N), np.inf)
+    arg = np.zeros((N, N), np.int64)
+    rows = np.flatnonzero(assign >= 0)
+    if rows.size:
+        # Rows grouped by column, ascending within each group.
+        order = rows[np.argsort(assign[rows], kind="stable")]
+        cols, starts = np.unique(assign[order], return_index=True)
+        Gs = G[order]
+        low = np.minimum.reduceat(Gs, starts, axis=0)
+        size = np.diff(np.append(starts, len(order)))
+        at = np.where(Gs == np.repeat(low, size, axis=0), order[:, None],
+                      len(assign))
+        best[cols] = low
+        arg[cols] = np.minimum.reduceat(at, starts, axis=0)
+    delta = best + best.T
+    np.fill_diagonal(delta, np.inf)
+    p, q = np.unravel_index(np.argmin(delta), delta.shape)
+    return arg[p, q], arg[q, p], delta[p, q]
 
 
 def _effective(cost, allowed, soften, overrun, tol, sigma):

@@ -1,5 +1,6 @@
-"""Solver cross-validation: exactness of ``flow``, the soft-cost fold, and
-the Sinkhorn backend's integrality gap (paper Eqs 8-13)."""
+"""Solver cross-validation: exactness of ``flow``, the soft-cost fold, the
+Sinkhorn backend's integrality gap (paper Eqs 8-13), and the host polish
+against its quadratic formulation."""
 import numpy as np
 import pytest
 import scipy.optimize as sopt
@@ -7,6 +8,7 @@ import scipy.sparse as sp
 from hypothesis import given, settings, strategies as st
 
 from repro.core import solvers
+from repro.core.solvers import jax_solver
 
 
 def _random_instance(rng, M=None, N=None, feasible=True):
@@ -128,3 +130,141 @@ def test_flow_optimality_property(seed):
     assert (counts <= cap).all()
     assert all(allowed[m, r_flow.assign[m]] for m in range(cost.shape[0]))
     assert np.isclose(r_flow.objective, r_ref.objective, atol=1e-8)
+
+
+def _quadratic_2swap(assign, cost, mask, capacity, rounds=3):
+    """The polish as first written: a Python loop over rows for the single
+    moves and a dense M x M delta matrix for the swap. Returns the
+    assignment and ``(passes, moves, swaps)``."""
+    M, N = cost.shape
+    used = np.bincount(assign[assign >= 0], minlength=N)
+    passes = moves = swaps = 0
+    for _ in range(rounds):
+        passes += 1
+        improved = False
+        for m in range(M):
+            if assign[m] < 0:
+                continue
+            cur = assign[m]
+            deltas = np.where(mask[m] & (used < capacity),
+                              cost[m] - cost[m, cur], np.inf)
+            deltas[cur] = np.inf
+            n = int(np.argmin(deltas))
+            if deltas[n] < -1e-12:
+                used[cur] -= 1
+                used[n] += 1
+                assign[m] = n
+                moves += 1
+                improved = True
+        a = assign
+        ok = a >= 0
+        cm = cost[np.arange(M), np.where(ok, a, 0)]
+        c_m1_a2 = np.where(mask[:, a] & ok[None, :], cost[:, a], np.inf)
+        delta = c_m1_a2 + c_m1_a2.T - cm[:, None] - cm[None, :]
+        delta[~ok] = np.inf
+        delta[:, ~ok] = np.inf
+        np.fill_diagonal(delta, np.inf)
+        m1, m2 = np.unravel_index(np.argmin(delta), delta.shape)
+        if delta[m1, m2] < -1e-12:
+            assign[m1], assign[m2] = assign[m2], assign[m1]
+            swaps += 1
+            improved = True
+        if not improved:
+            break
+    return assign, (passes, moves, swaps)
+
+
+def _polish(monkeypatch, assign, cost, mask, capacity, rounds=3):
+    """``jax_solver._improve_2swap`` on a copy of ``assign``, with the
+    ``(passes, moves, swaps)`` it annotates."""
+    seen = {}
+    monkeypatch.setattr(jax_solver.obs, "annotate", seen.update)
+    out = jax_solver._improve_2swap(assign.copy(), cost, mask, capacity,
+                                    rounds)
+    return out, (seen["passes"], seen["moves"], seen["swaps"])
+
+
+def _polish_instance(rng, M, N, soft, tight):
+    """A feasible rounded assignment to polish: each row on a random allowed
+    column, every column with spare capacity; ``soft`` allows every arc (the
+    Eqs 12-13 rounds); ``tight`` makes column 0 cheap with 2 spare slots,
+    the shape of a cheap region beside the defer arc."""
+    cost = rng.random((M, N)) * 10
+    mask = np.ones((M, N), bool) if soft else rng.random((M, N)) < 0.6
+    assign = rng.integers(0, N, M)
+    mask[np.arange(M), assign] = True
+    used = np.bincount(assign, minlength=N)
+    capacity = used + rng.integers(0, max(M // N, 2), N)
+    if tight:
+        cost[:, 0] -= 5.0
+        capacity[0] = used[0] + 2
+    return assign, cost, mask, capacity
+
+
+def _assert_feasible(assign, mask, capacity):
+    assert (assign >= 0).all()
+    assert mask[np.arange(len(assign)), assign].all()
+    assert (np.bincount(assign, minlength=len(capacity)) <= capacity).all()
+
+
+@pytest.mark.parametrize("seed", range(2))
+@pytest.mark.parametrize("tight", [False, True], ids=["loose", "tight"])
+@pytest.mark.parametrize("soft", [False, True], ids=["masked", "soft"])
+@pytest.mark.parametrize("N", [2, 6, 40])
+def test_polish_matches_quadratic_reference(monkeypatch, N, soft, tight,
+                                            seed):
+    """The O(M·N) polish returns the quadratic one's assignment, passes,
+    moves and swaps (random costs: no two swaps tie), and stays within
+    capacity and mask."""
+    rng = np.random.default_rng(1000 * N + 10 * soft + 2 * tight + seed)
+    M = int(rng.integers(N + 1, 600))
+    assign, cost, mask, capacity = _polish_instance(rng, M, N, soft, tight)
+    ref, ref_counts = _quadratic_2swap(assign.copy(), cost, mask, capacity)
+    out, counts = _polish(monkeypatch, assign, cost, mask, capacity)
+    np.testing.assert_array_equal(out, ref)
+    assert counts == ref_counts
+    _assert_feasible(out, mask, capacity)
+
+
+@pytest.mark.parametrize("block", [1, 7, 10 ** 6])
+def test_polish_does_not_depend_on_scan_block(monkeypatch, block):
+    """The single-move scan's block size changes only how rows are priced,
+    never which rows move, where or in what order."""
+    monkeypatch.setattr(jax_solver, "_SCAN_BLOCK", block)
+    for seed, (N, soft, tight) in enumerate([(6, False, True),
+                                             (40, True, False),
+                                             (2, False, False)]):
+        rng = np.random.default_rng(77 + seed)
+        assign, cost, mask, capacity = _polish_instance(rng, 500, N, soft,
+                                                        tight)
+        ref, ref_counts = _quadratic_2swap(assign.copy(), cost, mask,
+                                           capacity)
+        out, counts = _polish(monkeypatch, assign, cost, mask, capacity)
+        np.testing.assert_array_equal(out, ref)
+        assert counts == ref_counts
+        _assert_feasible(out, mask, capacity)
+
+
+def test_polish_pinned_cheap_column_nearly_full(monkeypatch):
+    """3,000 rows, 6 columns: 2,500 rows on the defer-like last column, the
+    rest on their cheapest other column but 0; every row is cheaper on
+    column 0, which has 9 spare slots, and the other regions are full.
+    Capacity blocks all but 9 of the would-be moves, so the first pass
+    moves at most 9 rows, and the whole polish agrees with the quadratic
+    one."""
+    rng = np.random.default_rng(31)
+    M, N = 3000, 6
+    cost = rng.random((M, N)) + np.array([0.0, 1.0, 1.0, 1.0, 1.0, 0.5])
+    mask = rng.random((M, N)) < 0.9
+    mask[:, [0, N - 1]] = True
+    cheapest = 1 + np.where(mask[:, 1:], cost[:, 1:], np.inf).argmin(axis=1)
+    assign = np.where(np.arange(M) < 2500, N - 1, cheapest)
+    used = np.bincount(assign, minlength=N)
+    capacity = used + np.array([9, 0, 0, 0, 0, 500])
+    _, first = _polish(monkeypatch, assign, cost, mask, capacity, rounds=1)
+    assert first[1] <= 9
+    ref, ref_counts = _quadratic_2swap(assign.copy(), cost, mask, capacity)
+    out, counts = _polish(monkeypatch, assign, cost, mask, capacity)
+    np.testing.assert_array_equal(out, ref)
+    assert counts == ref_counts
+    _assert_feasible(out, mask, capacity)
