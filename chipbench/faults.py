@@ -11,8 +11,12 @@ of a run and restores it after.
 - ``half_batch``: the second half of every answer is left unassigned.
 - ``unchanged_state``: the scheduler returns every job it was given,
   placing none.
-
-There is no exchange between chips in a one-chip cell to leave out.
+- ``stale_plan``: the host rounds the previous solve's plan in place of
+  its own: the first ``min(M, M')`` rows of that plan, each row past them
+  uniform over its allowed arcs. It fires on every solve but the first.
+  Where several fleets share a run, the previous solve is as a rule
+  another fleet's: the fault that an exchange between fleets, or between
+  the chips that batch their solves, can have.
 
 ``late_dispatch`` is not among ``FAULTS``: it leaves every answer as the
 reference would give it and moves ``failed``, not ``correct``. It starts
@@ -73,6 +77,22 @@ def half_batch():
     return _wrap_finalize(after=halve)
 
 
+def stale_plan():
+    last = []
+
+    def stale(X, mask):
+        prev, last[:] = (last[0] if last else None), [X]
+        if prev is None:
+            return X
+        m = np.asarray(mask, np.float64)
+        out = m / np.maximum(m.sum(axis=1, keepdims=True), 1.0)
+        rows, cols = min(len(X), len(prev)), min(X.shape[1], prev.shape[1])
+        out[:rows] = 0.0
+        out[:rows, :cols] = prev[:rows, :cols]
+        return out
+    return _wrap_finalize(before=stale)
+
+
 def unchanged_state():
     from repro.policy import pipeline
 
@@ -99,4 +119,5 @@ def late_dispatch(at_s: float):
 
 
 FAULTS = dict(cost_blind=cost_blind, altered_answer=altered_answer,
-              half_batch=half_batch, unchanged_state=unchanged_state)
+              half_batch=half_batch, unchanged_state=unchanged_state,
+              stale_plan=stale_plan)

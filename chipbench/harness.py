@@ -13,9 +13,16 @@ the configuration's telemetry, and runs the traffic's warm-up rounds; the
 window closes with the first round that ends after
 ``--seconds``, or where the stream ends.
 
+A configuration may state ``fleets`` (1 where absent): independent fleets
+served by one process, each with its own policy, engine, ``DecisionLoop``
+and job stream (``stream.py`` gives the seeding rule), all over the one
+telemetry of the regions they share. At each boundary one loop steps
+them all (``fleet_loop``); one ``RoundStat`` is one boundary.
+
 Inside the window the harness keeps only references to what each round
-was asked and answered, and the transport plan each solve handed to the
-host's rounding; every comparison runs after the window closes.
+was asked and answered, the answer carrying the transport plan its solve
+handed to the host's rounding (``plan_field``); every comparison runs
+after the window closes.
 """
 from __future__ import annotations
 
@@ -30,6 +37,7 @@ import os
 import pathlib
 import shutil
 import tempfile
+import threading
 import time
 from typing import List, Optional
 
@@ -118,12 +126,10 @@ def peaks_of(device_kind: str) -> dict:
 @dataclasses.dataclass
 class Call:
     """One ``schedule`` call: the priced question (index into
-    ``Recorder.priced``, None where nothing was priced), the decision, the
-    plan handed to the host's rounding (index into ``Recorder.plans``, None
-    where no solve rounded one) and the call's ``perf_counter`` span."""
+    ``Recorder.priced``, None where nothing was priced), the decision and
+    the call's ``perf_counter`` span."""
     priced: Optional[int]
     decision: object
-    plan: Optional[int]
     t0: float
     t1: float
 
@@ -131,17 +137,64 @@ class Call:
     def wall_s(self) -> float:
         return self.t1 - self.t0
 
+    @property
+    def plan(self) -> Optional[np.ndarray]:
+        """The transport plan the decision's solve handed to the host's
+        rounding, read from its result (``plan``, ``plan_mask``), nought
+        off the arcs it rounded under; None where no solve rounded one."""
+        res = self.decision.solver
+        X = getattr(res, "plan", None)
+        if X is None:
+            return None
+        return np.where(res.plan_mask, X, 0.0)
+
+
+@contextlib.contextmanager
+def plan_field():
+    """While open, each solve's result carries the plan it rounded.
+
+    Where the program's ``SolveResult`` has the fields ``plan`` and
+    ``plan_mask``, the program fills them and nothing is patched.
+    Otherwise ``jax_solver._finalize`` sets them on the result it returns,
+    by reference, to the plan and mask its call of ``_round_to_vertex``
+    received: below any fault planted around ``_finalize``, and per
+    thread, so that a solve finalised on another thread, or for another
+    fleet, keeps its own plan. A result that no rounding made has None."""
+    from repro.core import solvers
+    from repro.core.solvers import jax_solver
+    fields = {f.name for f in dataclasses.fields(solvers.SolveResult)}
+    if {"plan", "plan_mask"} <= fields:
+        yield
+        return
+    seen = threading.local()
+    finalize, rounding = jax_solver._finalize, jax_solver._round_to_vertex
+
+    def tapped_rounding(X, cost, mask, capacity):
+        seen.plan = (X, mask)
+        return rounding(X, cost, mask, capacity)
+
+    def tapped_finalize(*args):
+        seen.plan = (None, None)
+        res = finalize(*args)
+        res.plan, res.plan_mask = seen.plan
+        return res
+
+    jax_solver._finalize = tapped_finalize
+    jax_solver._round_to_vertex = tapped_rounding
+    try:
+        yield
+    finally:
+        jax_solver._finalize, jax_solver._round_to_vertex = finalize, rounding
+
 
 class Recorder:
     """Delegating scheduler that times ``schedule`` and keeps references to
     each round's question (due jobs, decision time, free servers, the
-    priced plan's column count), its decision and the transport plan its
-    solve handed to the host's rounding (``tap_plans``)."""
+    priced plan's column count) and its decision."""
 
     def __init__(self, inner):
         self.inner = inner
         self.priced: list = []
-        self.plans: list = []           # (plan X, mask), in solve order
         self.calls: List[Call] = []
         pricer = inner.pricer
         price = pricer.price
@@ -154,32 +207,13 @@ class Recorder:
 
         pricer.price = tapped
 
-    @contextlib.contextmanager
-    def tap_plans(self):
-        """Keep a reference to every plan the host rounds while open. The
-        tap sits on ``jax_solver._round_to_vertex``, below ``_finalize``,
-        so it sees the plan a fault planted above it hands on."""
-        from repro.core.solvers import jax_solver
-        orig = jax_solver._round_to_vertex
-
-        def tapped(X, cost, mask, capacity):
-            self.plans.append((X, mask))
-            return orig(X, cost, mask, capacity)
-
-        jax_solver._round_to_vertex = tapped
-        try:
-            yield
-        finally:
-            jax_solver._round_to_vertex = orig
-
     def schedule(self, jobs, now_s, capacity):
-        n0, p0 = len(self.priced), len(self.plans)
+        n0 = len(self.priced)
         t0 = time.perf_counter()
         dec = self.inner.schedule(jobs, now_s, capacity)
         t1 = time.perf_counter()
         self.calls.append(Call(n0 if len(self.priced) > n0 else None, dec,
-                               len(self.plans) - 1
-                               if len(self.plans) > p0 else None, t0, t1))
+                               t0, t1))
         return dec
 
     def __getattr__(self, name):
@@ -188,8 +222,56 @@ class Recorder:
 
 @dataclasses.dataclass
 class RoundStat:
-    wall_s: float             # run_round
-    schedule_s: float         # the scheduler's share of it
+    wall_s: float             # one boundary's run_round, every fleet's
+    schedule_s: float         # the union of the fleets' schedule() spans
+
+
+@dataclasses.dataclass
+class Fleet:
+    """One fleet: its recorded policy and its ``DecisionLoop``, and where
+    its records stood when the window opened."""
+    rec: Recorder
+    loop: object
+    calls0: int = 0
+    placed0: int = 0
+    offered0: int = 0
+    shed0: int = 0
+
+    def mark(self) -> None:
+        self.calls0, self.placed0 = (len(self.rec.calls),
+                                     len(self.loop.stepper.placed))
+        self.offered0 = self.loop.admission.offered
+        self.shed0 = self.loop.admission.shed
+
+    @property
+    def window_calls(self) -> List[Call]:
+        return self.rec.calls[self.calls0:]
+
+    @property
+    def window_placed(self) -> list:
+        return self.loop.stepper.placed[self.placed0:]
+
+
+class InTurn:
+    """Steps every fleet's ``DecisionLoop`` in turn at a boundary, on the
+    default device; ``run_round`` returns the wall seconds."""
+
+    def __init__(self, loops):
+        self.loops = list(loops)
+
+    def run_round(self, t_k: float) -> float:
+        t0 = time.perf_counter()
+        for loop in self.loops:
+            loop.run_round(t_k)
+        return time.perf_counter() - t0
+
+
+def fleet_loop(loops):
+    """What steps several fleets at each boundary: the program's
+    ``serve.FleetLoop(loops)`` where it has one (it may batch the fleets'
+    solves over the chips), else ``InTurn``."""
+    from repro import serve
+    return getattr(serve, "FleetLoop", InTurn)(loops)
 
 
 class CompileCounter:
@@ -325,58 +407,73 @@ def run_cell(*, config: dict, traffic: dict, limits: dict, metrics: list,
 
     round_s = float(traffic["round_s"])
     R = config["regions"]
+    n_fleets = int(config.get("fleets", 1))
+    if n_fleets < 1:
+        raise BenchError(f"the configuration states {n_fleets} fleets")
     tele = telemetry.generate(**config["telemetry"])
     if tele.num_regions != R:
         raise BenchError(f"telemetry has {tele.num_regions} regions, the "
                          f"configuration states {R}")
-    src = bench_stream.Stream.from_traffic(
-        traffic, seed, R, span_s=tele.ci.shape[0] * 3600.0, phase=PHASE)
-    rec = Recorder(policy.build(config["policy"], tele))
+    t_stream = time.perf_counter()
+    srcs = [bench_stream.Stream.from_traffic(
+        traffic, seed, R, span_s=tele.ci.shape[0] * 3600.0, phase=PHASE,
+        fleet=i) for i in range(n_fleets)]
+    stream_s = time.perf_counter() - t_stream
     cap = np.full(R, int(config["servers_per_region"]), np.int64)
-    sim = EventSimulator(tele, cap, SimConfig(window_s=round_s))
-    loop = DecisionLoop(sim, rec, src,
-                        ServeConfig(round_s=round_s, queue_bound=10 ** 9))
-    loop.stepper = sim.stepper(rec, state=EngineState(
-        now=round_s, pending=[], applied_events=0,
-        cluster=Cluster(cap).export_state()))
-    _verify_policy(rec.inner, config, round_s)
+    fleets = []
+    for src in srcs:
+        rec = Recorder(policy.build(config["policy"], tele))
+        sim = EventSimulator(tele, cap, SimConfig(window_s=round_s))
+        loop = DecisionLoop(sim, rec, src,
+                            ServeConfig(round_s=round_s,
+                                        queue_bound=10 ** 9))
+        loop.stepper = sim.stepper(rec, state=EngineState(
+            now=round_s, pending=[], applied_events=0,
+            cluster=Cluster(cap).export_state()))
+        _verify_policy(rec.inner, config, round_s)
+        fleets.append(Fleet(rec, loop))
+    serve_loop = (fleets[0].loop if n_fleets == 1
+              else fleet_loop([f.loop for f in fleets]))
 
     def boundary(k: int) -> float:
         return (k + PHASE) * round_s
 
-    with rec.tap_plans():
-        k = _warm_up(loop, rec, boundary, int(traffic["warmup_rounds"]))
+    with plan_field():
+        t_warm = time.perf_counter()
+        k = _warm_up(serve_loop, fleets, boundary,
+                     int(traffic["warmup_rounds"]))
+        warmup_s = time.perf_counter() - t_warm
         gc.collect()
         gc.freeze()         # the stream and set-up stay out of collections
         setup_s = time.perf_counter() - t_start
         try:
-            out = _window(loop, rec, src, boundary, k, seconds, traced)
+            win = _window(serve_loop, fleets, boundary, k, seconds, traced,
+                          min(src.span_s for src in srcs))
         finally:
             gc.unfreeze()
-    calls0, placed0, offered0, shed0, rounds, window_s, trace_files, \
-        stream_end, compiles = out
 
     import jax
     device = dict(device)
-    mem = [d.memory_stats() or {}
-           for d in jax.local_devices()[:device.pop("chips", 1)]]
+    chips = device.pop("chips", 1)
+    mem = [d.memory_stats() or {} for d in jax.local_devices()[:chips]]
     device["memory_peak_bytes"] = int(max(m.get("peak_bytes_in_use", 0)
                                           for m in mem))
 
-    placed = loop.stepper.placed[placed0:]
-    window_calls = rec.calls[calls0:]
+    placed = [p for f in fleets for p in f.window_placed]
     solves = []
-    for c in window_calls:
-        if c.decision.solver is None:
-            continue
-        cols = R if c.decision.softened else rec.priced[c.priced][3]
-        solves.append(dict(rows=int(c.decision.solver.assign.shape[0]),
-                           cols=int(cols), schedule_s=c.wall_s, t0=c.t0,
-                           t1=c.t1))
-    run = Run(setup_s=setup_s, window_s=window_s, rounds=rounds,
+    for f in fleets:
+        for c in f.window_calls:
+            if c.decision.solver is None:
+                continue
+            cols = R if c.decision.softened else f.rec.priced[c.priced][3]
+            solves.append(dict(rows=int(c.decision.solver.assign.shape[0]),
+                               cols=int(cols), schedule_s=c.wall_s, t0=c.t0,
+                               t1=c.t1))
+    solves.sort(key=lambda sv: sv["t0"])
+    run = Run(setup_s=setup_s, window_s=win.window_s, rounds=win.rounds,
               placed=len(placed), solves=solves)
     if traced:
-        _attach_trace(run, trace_files, device)
+        _attach_trace(run, win.trace_files, device, chips)
 
     out_metrics = {}
     for m in metrics:
@@ -390,18 +487,21 @@ def run_cell(*, config: dict, traffic: dict, limits: dict, metrics: list,
             if finish > deadline_s(job) + 1e-6]
     unreachable = sum(late_on_arrival(job, round_s) for job in late)
     t_ref = time.perf_counter()
-    checks, readings = compare(rec, window_calls, tele, config, round_s,
-                               limits, int(traffic["reference_rounds"]),
-                               seed, compiles)
+    checks, readings = compare(fleets, tele, config, round_s, limits,
+                               int(traffic["reference_rounds"]), seed,
+                               win.compiles)
     readings["reference_s"] = time.perf_counter() - t_ref
     readings["late"] = len(late)
     readings["late_on_arrival"] = unreachable
-    readings["stream_end"] = stream_end
+    readings["stream_end"] = win.stream_end
+    readings["setup_stream_s"] = stream_s
+    readings["setup_warmup_s"] = warmup_s
     result = dict(correct=all(c["value"] <= c["limit"]
                               for c in checks.values()),
-                  attempted=loop.admission.offered - offered0,
-                  failed=(loop.admission.shed - shed0)
-                  + len(late) - unreachable,
+                  attempted=sum(f.loop.admission.offered - f.offered0
+                                for f in fleets),
+                  failed=sum(f.loop.admission.shed - f.shed0
+                             for f in fleets) + len(late) - unreachable,
                   metrics=out_metrics, device=device)
     if traced:
         result["device"].update(busy_s=run.trace.busy_s,
@@ -414,30 +514,43 @@ def run_cell(*, config: dict, traffic: dict, limits: dict, metrics: list,
     return result
 
 
-def _warm_up(loop, rec: Recorder, boundary, rounds: int) -> int:
-    """``rounds`` rounds, then the Eqs 12-13 soft fallback's program at the
-    last round's size, which a round takes where a job arrives with its
-    deadline already out of reach. Returns the last boundary index run."""
+def _warm_up(serve_loop, fleets: List[Fleet], boundary,
+             rounds: int) -> int:
+    """``rounds`` rounds, then, for each fleet, the Eqs 12-13 soft
+    fallback's program at its last round's size, which a round takes where
+    a job arrives with its deadline already out of reach. Returns the last
+    boundary index run."""
     from repro.core import solvers
     for k in range(1, rounds + 1):
-        loop.run_round(boundary(k))
-    if not rec.priced:
-        return rounds
-    jobs, _, capacity, _ = rec.priced[-1]
-    M, R = len(jobs), len(capacity)
-    sched = rec.inner
-    solvers.solve(np.ones((M, R)), np.ones((M, R), bool),
-                  np.full(R, M, np.int64), backend=sched.backend,
-                  soften=True, overrun=np.zeros((M, R)),
-                  tol=np.full(M, 0.5), sigma=sched.sigma)
+        serve_loop.run_round(boundary(k))
+    for f in fleets:
+        if not f.rec.priced:
+            continue
+        jobs, _, capacity, _ = f.rec.priced[-1]
+        M, R = len(jobs), len(capacity)
+        sched = f.rec.inner
+        solvers.solve(np.ones((M, R)), np.ones((M, R), bool),
+                      np.full(R, M, np.int64), backend=sched.backend,
+                      soften=True, overrun=np.zeros((M, R)),
+                      tol=np.full(M, 0.5), sigma=sched.sigma)
     return rounds
 
 
-def _window(loop, rec: Recorder, src, boundary, k: int, seconds: float,
-            traced: bool):
-    """The measured window, from boundary ``k + 1`` on."""
-    calls0, placed0 = len(rec.calls), len(loop.stepper.placed)
-    offered0, shed0 = loop.admission.offered, loop.admission.shed
+@dataclasses.dataclass
+class Window:
+    rounds: List[RoundStat]
+    window_s: float
+    trace_files: Optional[tuple]
+    stream_end: bool
+    compiles: int
+
+
+def _window(serve_loop, fleets: List[Fleet], boundary, k: int,
+            seconds: float, traced: bool, span_s: float) -> Window:
+    """The measured window, from boundary ``k + 1`` on, to the stream's
+    end at ``span_s`` at the latest."""
+    for f in fleets:
+        f.mark()
     trace_files = None
     if traced:
         import jax
@@ -456,15 +569,17 @@ def _window(loop, rec: Recorder, src, boundary, k: int, seconds: float,
         t_win = time.perf_counter()
         while True:
             k += 1
-            if boundary(k) > src.span_s:
+            if boundary(k) > span_s:
                 stream_end = True
                 break
-            n_calls = len(rec.calls)
+            n_calls = [len(f.rec.calls) for f in fleets]
             t0 = time.perf_counter()
-            loop.run_round(boundary(k))
+            serve_loop.run_round(boundary(k))
             t1 = time.perf_counter()
-            rounds.append(RoundStat(t1 - t0, sum(
-                c.wall_s for c in rec.calls[n_calls:])))
+            calls = bench_trace.union(
+                ((c.t0, c.t1) for f, n in zip(fleets, n_calls)
+                 for c in f.rec.calls[n:]), -math.inf, math.inf)
+            rounds.append(RoundStat(t1 - t0, sum(e - s for s, e in calls)))
             if t1 - t_win >= seconds:
                 break
     t_end = time.perf_counter()
@@ -475,14 +590,14 @@ def _window(loop, rec: Recorder, src, boundary, k: int, seconds: float,
         spans = bench_trace.load_spans(os.path.join(tmp, "obs.jsonl"),
                                        t0_perf)
         trace_files = (tmp, anchor, t_win, t_end, spans)
-    return (calls0, placed0, offered0, shed0, rounds, t_end - t_win,
-            trace_files, stream_end, compiles.count)
+    return Window(rounds, t_end - t_win, trace_files, stream_end,
+                  compiles.count)
 
 
 SOLVE_SPANS = ("solver.solve", "solver.fused_round")
 
 
-def _attach_trace(run: Run, trace_files, device: dict) -> None:
+def _attach_trace(run: Run, trace_files, device: dict, chips: int) -> None:
     tmp, anchor, t_win, t_end, spans = trace_files
     try:
         paths = glob.glob(os.path.join(tmp, "**", "*.xplane.pb"),
@@ -500,7 +615,8 @@ def _attach_trace(run: Run, trace_files, device: dict) -> None:
         return perf_s * 1e9 + offset
 
     run.trace = bench_trace.reduce(events, to_ns(t_win), to_ns(t_end),
-                                   spans=spans, perf_to_trace_ns=to_ns)
+                                   spans=spans, perf_to_trace_ns=to_ns,
+                                   chips=chips)
     run.peaks = peaks_of(device["kind"])
     # The program's solve spans inside each answered call: their seconds,
     # and the rows and Sinkhorn budget of the last, which gave the answer.
@@ -527,52 +643,51 @@ EXACT = ("no_answer", "mode", "unassigned", "over_capacity", "masked",
          "window_compiles")
 
 
-def compare(rec: Recorder, window_calls: List[Call], tele, config: dict,
-            round_s: float, limits: dict, sample: int, seed: int,
-            compiles: int) -> dict:
-    """Hold every round the window answered against the reference, and a
-    sample of them drawn from the seed, the largest among them, against its
-    exact optimum. Returns the checks, {name: {"value", "limit"}}: the
-    exact counts with limit 0, then each gap the cell's file limits; and
-    the readings compared with no limit (a gap the control does not
-    separate, and how many rounds were held against the reference)."""
+def compare(fleets: List[Fleet], tele, config: dict, round_s: float,
+            limits: dict, sample: int, seed: int, compiles: int) -> dict:
+    """Hold every round the window answered, in every fleet, against the
+    reference, and a sample of them drawn from the seed over all fleets'
+    rounds pooled, the largest among them, against its exact optimum. Each
+    fleet's rounds are priced on its own history. Returns the checks,
+    {name: {"value", "limit"}}: the exact counts summed over the fleets
+    with limit 0, then each gap, the largest over the fleets, with the
+    limit the cell's file gives; and the readings compared with no limit
+    (a gap the control does not separate, how many rounds were held
+    against the reference, the number of fleets)."""
     ref = reference.Reference(_tele_arrays(tele), config, round_s)
-    observed = [p[1] for p in rec.priced]
-    answered = [c for c in window_calls if c.priced is not None]
     counts = dict.fromkeys(EXACT, 0)
-    counts["no_answer"] = int(not answered)
     counts["window_compiles"] = compiles
-    rounds = []
-    for c in answered:
-        jobs, now_s, capacity, _ = rec.priced[c.priced]
-        res = c.decision.solver
-        plan = None
-        if c.plan is not None:
-            X, mask = rec.plans[c.plan]
-            plan = np.where(mask, X, 0.0)
-        r = reference.Round.of(jobs, now_s, capacity, res.assign,
-                               c.decision.softened, plan)
-        inst = ref.instance(r, observed[:c.priced + 1], with_cost=False)
-        for k, v in reference.check_round(inst, r).items():
-            counts[k] += v
-        rounds.append((c.priced, r))
+    rounds = []             # (the fleet's decision times, index, Round)
+    for f in fleets:
+        observed = [p[1] for p in f.rec.priced]
+        answered = [c for c in f.window_calls if c.priced is not None]
+        counts["no_answer"] += int(not answered)
+        for c in answered:
+            jobs, now_s, capacity, _ = f.rec.priced[c.priced]
+            r = reference.Round.of(jobs, now_s, capacity,
+                                   c.decision.solver.assign,
+                                   c.decision.softened, c.plan)
+            inst = ref.instance(r, observed[:c.priced + 1], with_cost=False)
+            for k, v in reference.check_round(inst, r).items():
+                counts[k] += v
+            rounds.append((observed, c.priced, r))
     # The gaps compare hard rounds, a sample drawn from the seed with the
     # largest among them. A soft (Eqs 12-13) round folds penalties of up to
     # sigma times the overrun into its costs, and the kernel resolves its
     # normalised base costs only coarsely, so the few soft rounds of a
     # window are sampled alike, but reported apart.
-    hard = [(i, r) for i, r in rounds if not r.softened]
-    soft = [(i, r) for i, r in rounds if r.softened]
-    picks = reference.sample_rounds([len(r.t) for _, r in hard], sample,
+    hard = [x for x in rounds if not x[2].softened]
+    soft = [x for x in rounds if x[2].softened]
+    picks = reference.sample_rounds([len(x[2].t) for x in hard], sample,
                                     seed)
     worst = dict.fromkeys(("plan_gap", "served_gap"),
                           0.0 if picks else math.inf)
     soft_worst = dict.fromkeys(("soft_plan_gap", "soft_served_gap"), 0.0)
     for group, out in ((hard, worst), (soft, soft_worst)):
         idxs = picks if group is hard else reference.sample_rounds(
-            [len(r.t) for _, r in soft], sample, seed)
+            [len(x[2].t) for x in soft], sample, seed)
         for i in idxs:
-            idx, r = group[i]
+            observed, idx, r = group[i]
             inst = ref.instance(r, observed[:idx + 1], with_cost=True)
             g = reference.gaps(inst, r)
             if g is None:
@@ -588,4 +703,5 @@ def compare(rec: Recorder, window_calls: List[Call], tele, config: dict,
     readings["rounds_solved_exactly"] = len(picks)
     readings["soft_rounds"] = len(soft)
     readings.update(soft_worst)
+    readings["fleets"] = len(fleets)
     return checks, readings

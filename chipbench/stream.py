@@ -18,6 +18,11 @@ of ``shuffle_s`` seconds (ending ``phase`` of a period past each multiple,
 where the harness's rounds end) its own jobs over its own arrival times in
 another order. So every seed brings each round the same set of sizes and
 arrivals, and seeds differ in the order alone.
+
+Where a configuration states several fleets, fleet ``i`` has a stream of
+its own: its jobs come from ``stream_seed + i``, and its periods are dealt
+from ``(seed, c)`` for fleet 0, which is the one-fleet stream, and from
+``(seed, c, i)`` for every other fleet.
 """
 from __future__ import annotations
 
@@ -71,14 +76,14 @@ def make_jobs(rng: np.random.Generator, arrivals: np.ndarray,
 
 class Stream:
     """Seeded job stream over [0, ``span_s``), generated when it is made.
-    With ``shuffle_s``, ``shuffle_seed`` deals each period's jobs over its
-    arrival times (module docstring)."""
+    With ``shuffle_s``, ``shuffle_seed`` and ``fleet`` deal each period's
+    jobs over its arrival times (module docstring)."""
 
     def __init__(self, jobs_per_day: float, *, seed: int, num_regions: int,
                  tolerance: float, diurnal_depth: float, burst: float,
                  duration_jitter: float, span_s: float,
                  shuffle_s: float = None, shuffle_seed: int = 0,
-                 phase: float = 0.0):
+                 phase: float = 0.0, fleet: int = 0):
         rate = float(jobs_per_day) / DAY
         lam_max = rate * (1 + diurnal_depth) * (1 + burst * 4)
         self.span_s = float(span_s)
@@ -96,7 +101,8 @@ class Stream:
             order = None
             if shuffle_s:
                 period = np.floor(t[keep] / shuffle_s + 1.0 - phase)
-                deal = np.random.default_rng((int(shuffle_seed), c))
+                deal = np.random.default_rng(
+                    (int(shuffle_seed), c) + ((int(fleet),) if fleet else ()))
                 order = np.lexsort((deal.random(period.size), period))
             for j in make_jobs(rng, t[keep], num_regions, tolerance,
                                duration_jitter, order):
@@ -106,16 +112,19 @@ class Stream:
 
     @classmethod
     def from_traffic(cls, traffic: dict, seed: int, num_regions: int,
-                     span_s: float, phase: float) -> "Stream":
-        """The traffic's stream, its periods (``round_s``, ending ``phase``
-        of a period past each multiple) dealt by the run's ``seed``."""
-        return cls(traffic["jobs_per_day"], seed=traffic["stream_seed"],
+                     span_s: float, phase: float,
+                     fleet: int = 0) -> "Stream":
+        """Fleet ``fleet``'s stream of the traffic, its periods
+        (``round_s``, ending ``phase`` of a period past each multiple)
+        dealt by the run's ``seed`` (module docstring)."""
+        return cls(traffic["jobs_per_day"],
+                   seed=traffic["stream_seed"] + fleet,
                    num_regions=num_regions, tolerance=traffic["tolerance"],
                    diurnal_depth=traffic["diurnal_depth"],
                    burst=traffic["burst"],
                    duration_jitter=traffic["duration_jitter"], span_s=span_s,
                    shuffle_s=traffic["round_s"], shuffle_seed=seed,
-                   phase=phase)
+                   phase=phase, fleet=fleet)
 
     def poll(self, until_s: float) -> List[Job]:
         """Jobs submitted before ``until_s`` not returned yet, in order."""

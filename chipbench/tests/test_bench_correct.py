@@ -7,9 +7,11 @@ program's failure.
 
 The tiny size keeps the cells' shapes (5 regions, 6 or 40 columns) with
 ~190 new jobs per round and one day of telemetry. On the CPU the Sinkhorn
-runs the XLA loop, whose plan lies up to 0.04 from the optimum at this
-size where the chip's Pallas kernel lies closer, so the plan limit here
-is 0.15; a cost-blind plan lies far off."""
+runs the XLA loop, whose plan lies 0.0001-0.0065 from the optimum at this
+size over six seeds of each cell, where the chip's Pallas kernel lies
+closer, so the plan limit here is 0.05. A cost-blind plan lies 0.35 off,
+and a stale plan, another round's rounded in place of its own, 0.13-0.17
+in the forecast cell and 0.40 in the waterwise cell."""
 import time
 
 import numpy as np
@@ -19,7 +21,7 @@ from chipbench import faults, harness, reference, stream as bench_stream
 from repro.policy import pipeline
 
 BENCH = harness.load_json(harness.ROOT / "BENCHMARK.json")
-LIMITS = {"plan_gap": 0.15}
+LIMITS = {"plan_gap": 0.05}
 
 
 def run(cell, seed, seconds=1.0):

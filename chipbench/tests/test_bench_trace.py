@@ -64,6 +64,24 @@ def test_reduce_averages_the_chips_and_clips_to_the_window():
         [100e-9, 550e-9, 700e-9])
 
 
+def test_reduce_reads_the_cells_chips_only():
+    """A one-chip cell on a host of two chips reads its own chip alone."""
+    L = "XLA Ops"
+    ev = [bt.Event("/device:TPU:0", L, "%a.1 = x", 100, 300),
+          bt.Event("/device:TPU:1", L, "%b.1 = x", 0, 1000)]
+    one = bt.reduce(ev, 0, 1000, chips=1)
+    assert one.busy_s == pytest.approx(200e-9)
+    assert one.idle_share == pytest.approx(0.8)
+    assert set(one.kernel_s) == {"a"}
+    alone = bt.reduce(ev[:1], 0, 1000)
+    assert one.busy_s == alone.busy_s and one.idle_gaps == alone.idle_gaps
+    both = bt.reduce(ev, 0, 1000, chips=2)
+    assert both.busy_s == pytest.approx((200e-9 + 1000e-9) / 2)
+    assert bt.reduce(ev, 0, 1000).busy_s == both.busy_s
+    with pytest.raises(ValueError):
+        bt.reduce(ev[1:], 0, 1000, chips=1)
+
+
 def test_reduce_a_recorded_trace(tmp_path):
     """A trace recorded here on the CPU, whose XLA client thread stands in
     for the device plane."""

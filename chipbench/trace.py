@@ -114,12 +114,18 @@ def reduce(events: Sequence[Event], lo_ns: float, hi_ns: float, *,
            spans: Sequence[tuple] = (),
            perf_to_trace_ns: Callable[[float], float] = lambda s: s * 1e9,
            plane: re.Pattern = DEVICE_PLANE, line: Callable[[str], bool]
-           = lambda name: name == OPS_LINE, top: int = 10) -> Reduction:
+           = lambda name: name == OPS_LINE, top: int = 10,
+           chips: Optional[int] = None) -> Reduction:
     """Busy and idle time of the device planes in [lo_ns, hi_ns], averaged
     over the planes (one per chip), summed device time per operation, and
     the ``top`` longest idle gaps, each named by the innermost host span
-    open at its midpoint (``host`` where none was)."""
+    open at its midpoint (``host`` where none was). With ``chips``, only
+    the planes ``/device:TPU:0`` to ``chips - 1`` count: the chips the
+    cell runs on, whatever else the host holds."""
     planes = sorted({e.plane for e in events if plane.match(e.plane)})
+    if chips is not None:
+        ours = {f"/device:TPU:{i}" for i in range(chips)}
+        planes = [p for p in planes if p in ours]
     if not planes:
         raise ValueError("the trace holds no device plane")
     busy_total, op_s = 0.0, {}
