@@ -338,6 +338,7 @@ def device_executor_phase(traffic: Traffic, *, cells: int = 8,
     """An ``cells``-seed plan of ``waterwise[backend=fused]`` cells at
     ``traffic``'s rate, tolerance, fleet load and round period, through
     ``device[devices=N]`` and through ``serial``; placements must match."""
+    import jax
     import jax.numpy as jnp
 
     from repro.core import round as fused_round
@@ -384,8 +385,8 @@ def device_executor_phase(traffic: Traffic, *, cells: int = 8,
              f"differently through device[devices={devices}] and serial")
 
     # The batch program's mesh: one shard of the cell axis on each device.
-    fn = fused_round._batch_callable(
-        devices, soften=False, sigma=10.0,
+    fn = fused_round._batch_program(
+        tuple(jax.devices()[:devices]), soften=False, sigma=10.0,
         impl=fused_round.sinkhorn_impl_default(), eps_min=0.005,
         interpret=False)
     shards = fn(jnp.zeros((cells, 3, 7, 6)), jnp.zeros((cells, 7, 2)),

@@ -338,20 +338,20 @@ def _request_statics(req: SolveRequest) -> dict:
 
 
 @functools.lru_cache(maxsize=None)
-def _batch_callable(devices: int, *, soften: bool, sigma: float, impl: str,
-                    eps_min: float, interpret: bool):
+def _batch_program(devices: tuple, *, soften: bool, sigma: float, impl: str,
+                   eps_min: float, interpret: bool):
     """The compiled device-parallel batch program for one static signature:
     ``vmap`` of the single-cell ``_assignment_body`` over a leading cell
-    axis, ``shard_map``-split across ``devices`` XLA devices when more than
-    one is available. Cached per (devices, statics) — jitted shapes cache
-    underneath as usual."""
+    axis, ``shard_map``-split across the XLA ``devices`` (a tuple) when
+    there is more than one. Cached per (devices, statics) — jitted shapes
+    cache underneath as usual."""
     one = functools.partial(_assignment_body, soften=soften, sigma=sigma,
                             impl=impl, eps_min=eps_min, interpret=interpret)
     fn = jax.vmap(one)
-    if devices > 1:
+    if len(devices) > 1:
         from jax.sharding import Mesh
         from jax.sharding import PartitionSpec as P
-        mesh = Mesh(np.asarray(jax.devices()[:devices]), ("cells",))
+        mesh = Mesh(np.asarray(devices), ("cells",))
         fn = jax.shard_map(fn, mesh=mesh,
                            in_specs=(P("cells"), P("cells"), P("cells")),
                            out_specs=(P("cells"), P("cells")),
@@ -370,7 +370,8 @@ def _batch_size(n: int, devices: int) -> int:
     return devices * p
 
 
-def fused_round_batch(requests, devices: int = 1) -> list:
+def fused_round_batch(requests, devices: int = 1,
+                      slots: Optional[int] = None) -> list:
     """Solve many independent cells' assignment rounds as device-parallel
     jitted programs — ONE dispatch per (bucket, dtype, statics) group
     instead of one per cell.
@@ -381,20 +382,33 @@ def fused_round_batch(requests, devices: int = 1) -> list:
     normalized costs and transport plan are **bitwise identical** to a
     per-cell ``fused_solve`` call (pinned in tests/test_device_executor.py),
     and the host-side vertex rounding consumes identical inputs. Groups are
-    padded to ``_batch_size`` by repeating the last cell (results sliced
-    off), keeping compiled batch shapes few and device shards equal-sized.
+    padded by repeating the last cell (results sliced off) to ``slots``
+    cells, a multiple of ``devices``, so that each row bucket has one
+    compiled batch shape whichever cells share it; without ``slots``, to
+    ``_batch_size``, which keeps compiled batch shapes few and device
+    shards equal-sized.
 
     Returns ``SolveResult``s in request order; per-request infeasibility
     (capacity shortfall / fully masked row) short-circuits exactly like
-    ``fused_solve``. ``obs`` counters: ``round.batch_compile`` counts fresh
-    program compiles (retrace accounting for the bench gate),
-    ``round.batch_solves`` counts cells served.
+    ``fused_solve``. Span ``solver.round_batch`` holds the call, annotated
+    with its true rows (jobs plus the balancing row), the padded rows the
+    device solved (bucket padding and repeated slots included), the slots
+    and the chips; span ``solver.batch_device`` each group's dispatch
+    through its copy to the host. ``obs`` counters: ``round.batch_compile``
+    counts fresh program compiles (retrace accounting for the bench gate),
+    ``round.batch_solves`` cells served, and ``solver.batch_rows``,
+    ``solver.batch_padded_rows``, ``solver.batch_sinkhorn_iters`` (Sinkhorn
+    iterations over the cells served) and ``solver.batch_chips`` (chips
+    per group dispatch) the work.
     """
     devices = max(1, int(devices))
     n_avail = len(jax.devices())
     if devices > n_avail:
         raise ValueError(f"devices={devices} exceeds the {n_avail} "
                          f"available XLA device(s)")
+    if slots is not None and (slots < 1 or slots % devices):
+        raise ValueError(f"slots={slots} is not a positive multiple of "
+                         f"devices={devices}")
     results: list = [None] * len(requests)
     live: list = []
     for i, r in enumerate(requests):
@@ -409,6 +423,8 @@ def fused_round_batch(requests, devices: int = 1) -> list:
     if not live:
         return results
     groups = group_requests([requests[i] for i in live])
+    iters = jax_solver.SINKHORN_ITERS * jax_solver.SINKHORN_STAGES
+    rows = padded = n_slots = 0
     with obs.timed("solver.round_batch", requests=len(requests),
                    groups=len(groups), devices=devices) as t:
         for key, local in groups.items():
@@ -430,21 +446,30 @@ def fused_round_batch(requests, devices: int = 1) -> list:
                     _pad0(np.ones(M), pad)], axis=1).astype(np.float32))
                 cap_l.append(np.asarray(r.capacity).astype(np.int64)
                              .astype(np.float32))
+                rows += M + 1
             B = len(idxs)
-            for _ in range(_batch_size(B, devices) - B):
+            S = _batch_size(B, devices) if slots is None else slots
+            if B > S:
+                raise ValueError(f"{B} cells share a row bucket, more than "
+                                 f"slots={slots}")
+            for _ in range(S - B):
                 arcs_l.append(arcs_l[-1])
                 tolv_l.append(tolv_l[-1])
                 cap_l.append(cap_l[-1])
-            fn = _batch_callable(devices, **statics)
+            padded += bucket * S
+            n_slots += S
+            fn = _batch_program(tuple(jax.devices()[:devices]), **statics)
             before = fn._cache_size()
-            out = fn(jnp.asarray(np.stack(arcs_l)),
-                     jnp.asarray(np.stack(tolv_l)),
-                     jnp.asarray(np.stack(cap_l)))
+            args = (np.stack(arcs_l), np.stack(tolv_l), np.stack(cap_l))
+            with obs.span("solver.batch_device", bucket=bucket, cells=B,
+                          slots=S, chips=devices):
+                Cnb, Xb = jax_solver.fetch(fn(*map(jnp.asarray, args)))
             compiles = fn._cache_size() - before
             if compiles:
                 obs.counter("round.batch_compile", compiles)
             _count_sinkhorn(statics["impl"], statics["interpret"], B)
-            Cnb, Xb = jax_solver.fetch(out)
+            obs.counter("solver.batch_sinkhorn_iters", B * iters)
+            obs.counter("solver.batch_chips", devices)
             for b, i in enumerate(idxs):
                 r = requests[i]
                 M = r.cost.shape[0]
@@ -460,6 +485,10 @@ def fused_round_batch(requests, devices: int = 1) -> list:
                 res.backend = "fused"
                 results[i] = res
         obs.counter("round.batch_solves", len(live))
+        obs.counter("solver.batch_rows", rows)
+        obs.counter("solver.batch_padded_rows", padded)
+        obs.annotate(rows=rows, padded_rows=padded, slots=n_slots,
+                     chips=devices)
     per = t.elapsed_s / max(len(requests), 1)
     for r in results:
         r.solve_time_s = per

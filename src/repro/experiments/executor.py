@@ -44,6 +44,7 @@ from typing import Dict, List, Optional, Sequence, Union
 
 import repro.obs as obs
 from repro.core import solvers
+from repro.core.lockstep import LockstepBatcher
 from repro.experiments import runner
 from repro.experiments.plan import Cell
 from repro.launch import devices as launch_devices
@@ -215,74 +216,6 @@ class ShardedExecutor(Executor):
         return [self._guarded(one, c) for c in cells]
 
 
-class _CellBatcher:
-    """Lockstep cross-cell solve batcher (the ``device`` backend's core).
-
-    Every participating cell runs in its own thread and funnels each
-    ``fused`` solve here via :func:`repro.core.solvers.intercepted`;
-    :meth:`submit` blocks until the whole wave's requests are flushed as
-    one device-parallel batch (``flush_fn``) and the caller's result is
-    back.
-
-    Liveness invariant: a flush fires exactly when every *active* thread
-    is blocked in :meth:`submit` — the last arrival executes the flush.
-    A thread that will submit nothing more MUST :meth:`finish` (the
-    executor does so in a ``finally``), which both removes it from the
-    barrier arithmetic and flushes any wave it was holding up. Cells make
-    different numbers of solves (different round counts, hard + soft
-    fallback rounds): late waves simply batch across whichever cells are
-    still running, down to single-request "batches" for the last cell
-    standing — identical results, less amortization.
-
-    A flush exception fans out to every waiting ``submit`` (re-raised in
-    each cell thread → that cell's error row); the batcher itself stays
-    usable for the survivors.
-    """
-
-    def __init__(self, flush_fn):
-        self._flush_fn = flush_fn
-        self._cv = threading.Condition()
-        self._active = 0
-        self._pending: List[list] = []      # [request, result, exception]
-
-    def register(self) -> None:
-        with self._cv:
-            self._active += 1
-
-    def finish(self) -> None:
-        with self._cv:
-            self._active -= 1
-            self._maybe_flush()
-
-    def submit(self, request):
-        item = [request, None, None]
-        with self._cv:
-            self._pending.append(item)
-            self._maybe_flush()
-            while item[1] is None and item[2] is None:
-                self._cv.wait()
-        if item[2] is not None:
-            raise item[2]
-        return item[1]
-
-    def _maybe_flush(self) -> None:
-        # Caller holds the lock. Every active thread pending -> flush now.
-        # (The non-submitting threads are all inside submit(), waiting, so
-        # holding the lock across the flush serializes nothing that could
-        # otherwise run.)
-        if not self._pending or len(self._pending) < self._active:
-            return
-        batch, self._pending = self._pending, []
-        try:
-            results = self._flush_fn([it[0] for it in batch])
-            for it, res in zip(batch, results):
-                it[1] = res
-        except BaseException as e:          # noqa: BLE001 — fan out to cells
-            for it in batch:
-                it[2] = e
-        self._cv.notify_all()
-
-
 class DeviceExecutor(Executor):
     """Device-parallel cell execution: one engine thread per cell, the
     cells' fused scheduling solves batched into ONE vmapped/shard_mapped
@@ -320,7 +253,7 @@ class DeviceExecutor(Executor):
         return not entry.forecast_driven and params.get("backend") == "fused"
 
     def _run_threaded(self, cell: Cell, i: int, rows: List,
-                      batcher: _CellBatcher) -> None:
+                      batcher: LockstepBatcher) -> None:
         from repro.core.round import SolveRequest
 
         def hook(cost, allowed, capacity, *, backend, soften, overrun, tol,
@@ -359,7 +292,7 @@ class DeviceExecutor(Executor):
         wave = self.max_cells or max(len(batched), 1)
         for start in range(0, len(batched), wave):
             chunk = batched[start:start + wave]
-            batcher = _CellBatcher(
+            batcher = LockstepBatcher(
                 lambda reqs: fused_round.fused_round_batch(
                     reqs, devices=devices))
             threads = []

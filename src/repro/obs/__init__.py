@@ -12,7 +12,9 @@ Three pillars:
   identity (``sid``) and its parent's (``parent``), and while enabled
   each span also enters a ``jax.profiler.TraceAnnotation`` of its name,
   so a running profiler session records the program's spans on its own
-  clock beside the device ops;
+  clock beside the device ops. Spans nest per thread (a span's parent is
+  the innermost span open on its own thread), and the registry and the
+  writer take a lock, so threads may record at once;
 * runtime hooks while enabled: generation-2 garbage collections as
   ``host.gc`` spans (every collection's seconds add to ``host.gc_s``),
   and JAX's backend compiles as ``jax.compile`` spans; the ``jit/traces``
@@ -54,6 +56,7 @@ from __future__ import annotations
 import contextlib
 import gc
 import itertools
+import threading
 import time
 import warnings
 from typing import Dict, List, Optional
@@ -75,7 +78,11 @@ __all__ = [
 _REGISTRY = MetricsRegistry()
 _TRACER: Optional[TraceWriter] = None
 _ENABLED = False
-_STACK: List["_Span"] = []
+# Each thread's open spans, innermost last: a span's ``parent`` and
+# :func:`annotate` reach the calling thread's own. ``disable`` empties every
+# thread's stack by moving the epoch on.
+_LOCAL = threading.local()
+_EPOCH = 0
 _SIDS = itertools.count(1)          # span identities, unique per process
 # ``jax.profiler.TraceAnnotation`` while enabled (imported by ``enable``),
 # else None: spans then enter no profiler annotation.
@@ -89,6 +96,18 @@ _SESSION_END: Optional[Dict] = None
 
 def enabled() -> bool:
     return _ENABLED
+
+
+def _stack() -> List["_Span"]:
+    """The calling thread's open spans, innermost last."""
+    if getattr(_LOCAL, "epoch", None) != _EPOCH:
+        _LOCAL.epoch, _LOCAL.stack = _EPOCH, []
+    return _LOCAL.stack
+
+
+def _parent_sid() -> Optional[int]:
+    st = _stack()
+    return st[-1].sid if st else None
 
 
 def registry() -> MetricsRegistry:
@@ -123,7 +142,7 @@ def disable() -> None:
     """Stop collection and close any open trace file. The metrics
     registry is kept (read it with :func:`snapshot`; clear with
     :func:`reset`). Closes the open :func:`session`."""
-    global _ENABLED, _TRACER, _ANNOTATION, _GC_SPAN, _SESSION_END
+    global _ENABLED, _TRACER, _ANNOTATION, _GC_SPAN, _SESSION_END, _EPOCH
     if _SESSION_START is not None and _SESSION_END is None:
         _SESSION_END = _totals()
     _ENABLED = False
@@ -134,7 +153,7 @@ def disable() -> None:
     if _TRACER is not None:
         _TRACER.close()
         _TRACER = None
-    _STACK.clear()
+    _EPOCH += 1
 
 
 @contextlib.contextmanager
@@ -172,9 +191,11 @@ def reset() -> None:
 
 
 def _totals() -> Dict:
-    return {"counters": {k: c.value for k, c in _REGISTRY.counters.items()},
-            "spans": {k: (h.count, h.total)
-                      for k, h in _REGISTRY.hists.items()}}
+    with _REGISTRY.lock:
+        return {"counters": {k: c.value
+                             for k, c in _REGISTRY.counters.items()},
+                "spans": {k: (h.count, h.total)
+                          for k, h in _REGISTRY.hists.items()}}
 
 
 def session() -> Optional[Dict]:
@@ -245,9 +266,10 @@ class _Span:
 
     def __enter__(self):
         if not self._measure_only:
-            self.parent = _STACK[-1].sid if _STACK else None
+            stack = _stack()
+            self.parent = stack[-1].sid if stack else None
             self.sid = next(_SIDS)
-            _STACK.append(self)
+            stack.append(self)
             if _ANNOTATION is not None:
                 self._ann = _ANNOTATION(self.name)
                 self._ann.__enter__()
@@ -261,8 +283,9 @@ class _Span:
             return False
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
-        if _STACK and _STACK[-1] is self:
-            _STACK.pop()
+        stack = _stack()
+        if stack and stack[-1] is self:
+            stack.pop()
         _REGISTRY.observe(self.name, self.elapsed_s)
         if _TRACER is not None:
             self.args.update(sid=self.sid, parent=self.parent)
@@ -292,9 +315,12 @@ def timed(name: str, **args):
 
 
 def annotate(**args) -> None:
-    """Attach args to the innermost open (enabled) span, if any."""
-    if _STACK:
-        _STACK[-1].set(**args)
+    """Attach args to the calling thread's innermost open (enabled) span,
+    if any."""
+    if _ENABLED:
+        stack = _stack()
+        if stack:
+            stack[-1].set(**args)
 
 
 # ---------------------------------------------------------------------------
@@ -343,8 +369,7 @@ def _on_jax_event(event: str, duration: float, **kwargs) -> None:
         _TRACER.complete("jax.compile", (t1 - duration - _TRACER._t0) * 1e6,
                          duration * 1e6, args=dict(
                              fun=str(kwargs.get("fun_name", "")),
-                             sid=next(_SIDS),
-                             parent=_STACK[-1].sid if _STACK else None))
+                             sid=next(_SIDS), parent=_parent_sid()))
 
 
 def watch_jax() -> None:

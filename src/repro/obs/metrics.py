@@ -23,6 +23,7 @@ their registries to the driver in any completion order.
 from __future__ import annotations
 
 import math
+import threading
 from typing import Dict, Iterable, List, Optional, Sequence
 
 import numpy as np
@@ -125,74 +126,82 @@ class Histogram:
 
 
 class MetricsRegistry:
-    """Named counters/gauges/histograms with snapshot + merge."""
+    """Named counters/gauges/histograms with snapshot + merge. Every write,
+    snapshot and merge holds ``lock``, so threads may share a registry."""
 
     def __init__(self) -> None:
         self.counters: Dict[str, Counter] = {}
         self.gauges: Dict[str, Gauge] = {}
         self.hists: Dict[str, Histogram] = {}
+        self.lock = threading.RLock()   # a GC hook may count mid-update
 
     # -- write paths -----------------------------------------------------
     def counter(self, name: str, n: float = 1) -> None:
-        c = self.counters.get(name)
-        if c is None:
-            c = self.counters[name] = Counter()
-        c.inc(n)
+        with self.lock:
+            c = self.counters.get(name)
+            if c is None:
+                c = self.counters[name] = Counter()
+            c.inc(n)
 
     def gauge(self, name: str, value: float, weight: float = 1.0) -> None:
-        g = self.gauges.get(name)
-        if g is None:
-            g = self.gauges[name] = Gauge()
-        g.set(value, weight)
+        with self.lock:
+            g = self.gauges.get(name)
+            if g is None:
+                g = self.gauges[name] = Gauge()
+            g.set(value, weight)
 
     def observe(self, name: str, value: float) -> None:
-        h = self.hists.get(name)
-        if h is None:
-            h = self.hists[name] = Histogram()
-        h.observe(value)
+        with self.lock:
+            h = self.hists.get(name)
+            if h is None:
+                h = self.hists[name] = Histogram()
+            h.observe(value)
 
     # -- snapshot / merge ------------------------------------------------
     def snapshot(self) -> Dict:
-        return {
-            "counters": {k: c.value for k, c in self.counters.items()},
-            "gauges": {k: {"value": g.value, "weight": g.weight}
-                       for k, g in self.gauges.items()},
-            "hists": {k: {
-                "counts": {str(i): n for i, n in h.counts.items()},
-                "samples": None if h.samples is None else list(h.samples),
-                "count": h.count,
-                "total": h.total,
-                "min": None if h.count == 0 else h.vmin,
-                "max": None if h.count == 0 else h.vmax,
-                "max_samples": h.max_samples,
-            } for k, h in self.hists.items()},
-        }
+        with self.lock:
+            return {
+                "counters": {k: c.value for k, c in self.counters.items()},
+                "gauges": {k: {"value": g.value, "weight": g.weight}
+                           for k, g in self.gauges.items()},
+                "hists": {k: {
+                    "counts": {str(i): n for i, n in h.counts.items()},
+                    "samples": (None if h.samples is None
+                                else list(h.samples)),
+                    "count": h.count,
+                    "total": h.total,
+                    "min": None if h.count == 0 else h.vmin,
+                    "max": None if h.count == 0 else h.vmax,
+                    "max_samples": h.max_samples,
+                } for k, h in self.hists.items()},
+            }
 
     def merge(self, snap: Dict) -> None:
         """Fold a snapshot (e.g. shipped back by a shard worker) in."""
-        for k, v in snap.get("counters", {}).items():
-            self.counter(k, v)
-        for k, g in snap.get("gauges", {}).items():
-            self.gauge(k, g["value"], g["weight"])
-        for k, hs in snap.get("hists", {}).items():
-            h = self.hists.get(k)
-            if h is None:
-                h = self.hists[k] = Histogram(hs.get("max_samples",
-                                                     HIST_MAX_SAMPLES))
-            for i, n in hs["counts"].items():
-                i = int(i)
-                h.counts[i] = h.counts.get(i, 0) + n
-            h.count += hs["count"]
-            h.total += hs["total"]
-            if hs["min"] is not None:
-                h.vmin = min(h.vmin, hs["min"])
-                h.vmax = max(h.vmax, hs["max"])
-            other = hs["samples"]
-            if h.samples is None or other is None or \
-                    len(h.samples) + len(other) > h.max_samples:
-                h.samples = None
-            else:
-                h.samples.extend(other)
+        with self.lock:
+            for k, v in snap.get("counters", {}).items():
+                self.counter(k, v)
+            for k, g in snap.get("gauges", {}).items():
+                self.gauge(k, g["value"], g["weight"])
+            for k, hs in snap.get("hists", {}).items():
+                h = self.hists.get(k)
+                if h is None:
+                    h = self.hists[k] = Histogram(hs.get("max_samples",
+                                                         HIST_MAX_SAMPLES))
+                for i, n in hs["counts"].items():
+                    i = int(i)
+                    h.counts[i] = h.counts.get(i, 0) + n
+                h.count += hs["count"]
+                h.total += hs["total"]
+                if hs["min"] is not None:
+                    h.vmin = min(h.vmin, hs["min"])
+                    h.vmax = max(h.vmax, hs["max"])
+                other = hs["samples"]
+                if h.samples is None or other is None or \
+                        len(h.samples) + len(other) > h.max_samples:
+                    h.samples = None
+                else:
+                    h.samples.extend(other)
 
 
 def merge_snapshots(snaps: Iterable[Dict]) -> Dict:

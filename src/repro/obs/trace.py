@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import json
 import os
+import threading
 import time
 from typing import Dict, Iterator, List, Optional
 
@@ -36,9 +37,10 @@ class TraceWriter:
     """Append-only trace-event writer. Events are kept in memory and
     written out by :meth:`close`, or whenever ``BUFFER_EVENTS`` have
     gathered, so the hot path pays a list append and memory stays
-    bounded. Not thread-safe by design — the simulator is
-    single-threaded and shard workers each get their own process (and
-    would write their own file)."""
+    bounded. Threads may share a writer: the buffer and the file are
+    written under one lock, and each span goes on its thread's track
+    (``tid``, the thread's native id). Shard workers each get their own
+    process (and write their own file)."""
 
     BUFFER_EVENTS = 65536
 
@@ -51,6 +53,7 @@ class TraceWriter:
         self._t0 = time.perf_counter()
         self._pid = os.getpid()
         self._buf: List[Dict] = []
+        self._lock = threading.RLock()      # a GC span may land mid-write
         self.events_written = 0
         self.metadata("process_name", {"name": "repro"})
         self.metadata("process_name", {"name": "simulated-time"}, pid=SIM_PID)
@@ -61,13 +64,18 @@ class TraceWriter:
 
     # -- emitters --------------------------------------------------------
     def _emit(self, ev: Dict) -> None:
-        self._buf.append(ev)
-        self.events_written += 1
-        if len(self._buf) >= self.BUFFER_EVENTS:
-            self.flush()
+        with self._lock:
+            self._buf.append(ev)
+            self.events_written += 1
+            if len(self._buf) >= self.BUFFER_EVENTS:
+                self._write()
 
     def flush(self) -> None:
         """Write the buffered events to the file."""
+        with self._lock:
+            self._write()
+
+    def _write(self) -> None:
         self._f.write("".join(json.dumps(ev, separators=(",", ":")) + ",\n"
                               for ev in self._buf))
         self._f.flush()
@@ -77,7 +85,8 @@ class TraceWriter:
                  args: Optional[Dict] = None, cat: str = "repro") -> None:
         """A ``ph: "X"`` complete event (a span)."""
         ev = {"name": name, "ph": "X", "cat": cat, "ts": round(ts_us, 3),
-              "dur": round(max(dur_us, 0.0), 3), "pid": self._pid, "tid": 1}
+              "dur": round(max(dur_us, 0.0), 3), "pid": self._pid,
+              "tid": threading.get_native_id()}
         if args:
             ev["args"] = args
         self._emit(ev)
@@ -104,9 +113,10 @@ class TraceWriter:
                     "args": args})
 
     def close(self) -> None:
-        if not self._f.closed:
-            self.flush()
-            self._f.close()
+        with self._lock:
+            if not self._f.closed:
+                self._write()
+                self._f.close()
 
 
 # ---------------------------------------------------------------------------

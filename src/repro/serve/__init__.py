@@ -11,7 +11,9 @@ the same engine and policies:
                   ``AdmissionQueue`` with explicit shed accounting;
 * ``loop``      — the ``DecisionLoop`` driving ``EngineStepper`` rounds
                   (inject → step-to-boundary) with round-latency metrics,
-                  and the ``ServeReport``.
+                  the ``ServeReport``, and ``FleetLoop``, which steps
+                  several fleets' loops at each boundary with their
+                  solves batched over the devices.
 
 Receding-horizon re-planning and the Sinkhorn warm-start carry live in
 the *policy* (``waterwise-forecast[replan=true,warm=true]``) — the loop
@@ -22,10 +24,11 @@ and ``python -m benchmarks.serve_bench``.
 from repro.serve.arrivals import (DROP_OLDEST, REJECT_NEW, AdmissionQueue,
                                   ArrivalSource, FileTailArrivals,
                                   PoissonBurstArrivals, ReplayArrivals)
-from repro.serve.loop import DecisionLoop, ServeConfig, ServeReport
+from repro.serve.loop import (DecisionLoop, FleetLoop, ServeConfig,
+                              ServeReport)
 
 __all__ = [
     "ArrivalSource", "ReplayArrivals", "PoissonBurstArrivals",
     "FileTailArrivals", "AdmissionQueue", "REJECT_NEW", "DROP_OLDEST",
-    "DecisionLoop", "ServeConfig", "ServeReport",
+    "DecisionLoop", "FleetLoop", "ServeConfig", "ServeReport",
 ]

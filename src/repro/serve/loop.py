@@ -26,12 +26,15 @@ in the report as cold vs warm iterations-to-converge.
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
 import repro.obs as obs
+from repro.core import solvers
+from repro.core.lockstep import Baton, LockstepBatcher
 from repro.runtime.elastic import StepWatchdog
 from repro.serve.arrivals import REJECT_NEW, AdmissionQueue, ArrivalSource
 from repro.sim.engine import EventSimulator
@@ -171,3 +174,87 @@ class DecisionLoop:
             sinkhorn_cold_iters=float(np.mean(cold)) if cold else 0.0,
             sinkhorn_warm_iters=float(np.mean(warm)) if warm else 0.0,
             utilization=float(res["utilization"]))
+
+
+class FleetLoop:
+    """Several independent fleets served by one process: at each boundary,
+    every fleet's ``DecisionLoop.run_round(t_k)``, with the fleets' hard
+    ``fused`` solves batched into one device wave.
+
+    Each fleet steps in a thread of its own under
+    :func:`repro.core.solvers.intercepted`, and the threads take turns
+    (:class:`~repro.core.lockstep.Baton`): in fleet order, each runs its
+    host work up to its solve, then hands on. The last one to hand on
+    flushes the wave (:class:`~repro.core.lockstep.LockstepBatcher`)
+    through ``core.round.fused_round_batch`` over every visible device,
+    with one batch slot per fleet rounded up to a multiple of the devices,
+    so that each row bucket has one compiled batch shape; the host then
+    rounds each fleet's plan, and the fleets resume in turn to the end of
+    their rounds. The host work stays serial, as if the fleets were stepped
+    one after another, while the fleets' device programs run as one.
+
+    Soft (Eqs 12-13) solves and other backends run in the fleet's own
+    thread, on the one-fleet program. A fleet that makes no solve leaves
+    the wave without holding it up; an error in any fleet, or in the
+    flush, is raised by :meth:`run_round` once every fleet has stopped.
+    """
+
+    def __init__(self, loops: Sequence[DecisionLoop]):
+        import jax
+        self.loops = list(loops)
+        self.devices = len(jax.devices())
+        self.slots = -(-len(self.loops) // self.devices) * self.devices
+
+    def _flush(self, requests):
+        from repro.core.round import fused_round_batch
+        return fused_round_batch(requests, devices=self.devices,
+                                 slots=self.slots)
+
+    def run_round(self, t_k: float) -> float:
+        """Every fleet's round up to boundary ``t_k``; returns the wall
+        seconds of the boundary."""
+        n = len(self.loops)
+        batcher, baton = LockstepBatcher(self._flush), Baton(n)
+        errors: List[Optional[BaseException]] = [None] * n
+        threads = [threading.Thread(
+            target=self._step, args=(i, t_k, batcher, baton, errors),
+            name=f"fleet-{i}", daemon=True) for i in range(n)]
+        t0 = time.perf_counter()
+        with obs.span("serve.fleet_round", fleets=n, boundary_s=t_k):
+            for _ in threads:
+                batcher.register()
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join()
+        wall = time.perf_counter() - t0
+        for e in errors:
+            if e is not None:
+                raise e
+        return wall
+
+    def _step(self, i: int, t_k: float, batcher: LockstepBatcher,
+              baton: Baton, errors: list) -> None:
+        from repro.core.round import SolveRequest
+
+        def hook(cost, allowed, capacity, *, backend, soften, overrun, tol,
+                 sigma):
+            if backend != "fused" or soften:
+                return None             # decline: solve runs in-thread
+            baton.pass_on(i)
+            try:
+                return batcher.submit(SolveRequest(
+                    cost=cost, allowed=allowed, capacity=capacity,
+                    overrun=overrun, tol=tol, sigma=sigma))
+            finally:
+                baton.wait(i)
+
+        baton.wait(i)
+        try:
+            with solvers.intercepted(hook):
+                self.loops[i].run_round(t_k)
+        except BaseException as e:      # noqa: BLE001 — raised by run_round
+            errors[i] = e
+        finally:
+            batcher.finish()
+            baton.leave(i)

@@ -102,11 +102,43 @@ def test_batch_compile_reuse_across_calls():
     to the same power-of-two batch shape)."""
     rng = np.random.default_rng(4)
     fused_round_batch([_request(rng, M=9) for _ in range(3)], devices=1)
-    fn = fused_round._batch_callable(
-        1, **fused_round._request_statics(_request(rng, M=9)))
+    import jax
+
+    fn = fused_round._batch_program(
+        (jax.devices()[0],),
+        **fused_round._request_statics(_request(rng, M=9)))
     before = fn._cache_size()
     fused_round_batch([_request(rng, M=11) for _ in range(4)], devices=1)
     assert fn._cache_size() == before        # same bucket 16, same batch 4
+
+
+def test_fixed_slots_give_each_bucket_one_batch_shape():
+    """With ``slots``, a group is padded to the same batch shape however
+    many requests share its bucket; more than ``slots`` is an error."""
+    import jax
+
+    rng = np.random.default_rng(7)
+    single = _request(rng, M=9)
+    first = fused_round_batch([single], devices=1, slots=3)[0]
+    fn = fused_round._batch_program(
+        (jax.devices()[0],), **fused_round._request_statics(single))
+    before = fn._cache_size()
+    reqs = [_request(rng, M=m) for m in (9, 10, 11)]
+    for out, group in ((fused_round_batch(reqs, devices=1, slots=3), reqs),
+                       (fused_round_batch(reqs[:2], devices=1, slots=3),
+                        reqs[:2])):
+        for r, b in zip(group, out):
+            _assert_same_result(fused_round.fused_solve(
+                r.cost, r.allowed, r.capacity, soften=r.soften,
+                overrun=r.overrun, tol=r.tol, sigma=r.sigma), b)
+    assert fn._cache_size() == before        # bucket 16, 3 slots, once
+    _assert_same_result(first, fused_round.fused_solve(
+        single.cost, single.allowed, single.capacity, overrun=single.overrun,
+        tol=single.tol, sigma=single.sigma))
+    with pytest.raises(ValueError, match="more than slots"):
+        fused_round_batch(reqs + [_request(rng, M=12)], devices=1, slots=3)
+    with pytest.raises(ValueError, match="multiple"):
+        fused_round_batch(reqs, devices=1, slots=0)
 
 
 # ---------------------------------------------------------------------------
@@ -242,7 +274,7 @@ def test_device_executor_batchable_classification():
 def test_cell_batcher_flushes_on_finish_and_broadcasts_errors():
     """Barrier liveness: a finishing thread flushes waiters; a flush
     exception reaches every waiting submit."""
-    from repro.experiments.executor import _CellBatcher
+    from repro.core.lockstep import LockstepBatcher
 
     calls = []
 
@@ -250,7 +282,7 @@ def test_cell_batcher_flushes_on_finish_and_broadcasts_errors():
         calls.append(len(reqs))
         return [r * 10 for r in reqs]
 
-    b = _CellBatcher(flush)
+    b = LockstepBatcher(flush)
     b.register()
     assert b.submit(7) == 70                 # active=1 → immediate flush
     b.finish()
@@ -259,7 +291,7 @@ def test_cell_batcher_flushes_on_finish_and_broadcasts_errors():
     def boom(reqs):
         raise RuntimeError("device exploded")
 
-    b = _CellBatcher(boom)
+    b = LockstepBatcher(boom)
     b.register()
     with pytest.raises(RuntimeError, match="device exploded"):
         b.submit(1)

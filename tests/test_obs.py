@@ -3,6 +3,8 @@ merge, span nesting in the exported Chrome trace, warning counters on the
 degenerate paths, the report CLI, and the disabled-mode pin (obs off and
 obs on produce bit-identical engine records)."""
 import json
+import sys
+import threading
 import warnings
 
 import numpy as np
@@ -548,6 +550,56 @@ def test_served_round_nests_the_schedule_and_its_solves(tmp_path):
     assert all("serve.round" in ancestors(e) for e in calls)
     rounds = [e["args"]["round"] for e in spans if e["name"] == "serve.round"]
     assert rounds == sorted(rounds) and len(set(rounds)) == len(rounds)
+
+
+def test_spans_and_counters_are_per_thread_under_many_threads(tmp_path):
+    """Threads that open nested spans and bump one counter at once: each
+    span's parent is its own thread's enclosing span, every annotation
+    lands on its own thread's span, no span stays open on any stack, and
+    the counter adds up."""
+    n_threads, rounds = 12, 40
+    path = tmp_path / "t.jsonl"
+    start = threading.Barrier(n_threads)
+    left = {}
+
+    def work(i):
+        start.wait(timeout=30)
+        for r in range(rounds):
+            with obs.span("t.outer", thread=i, r=r):
+                for k in range(3):
+                    with obs.span("t.inner", k=k):
+                        obs.counter("t/threads")
+                        obs.annotate(thread=i, r=r)
+        left[i] = len(obs._stack())
+
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with obs.capture(trace_path=str(path), fresh=False):
+            before = obs.counter_value("t/threads")
+            threads = [threading.Thread(target=work, args=(i,))
+                       for i in range(n_threads)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in threads)
+            counted = obs.counter_value("t/threads") - before
+    finally:
+        sys.setswitchinterval(switch)
+    assert counted == n_threads * rounds * 3
+    assert left == dict.fromkeys(range(n_threads), 0)
+    spans = _spans(path)
+    by_sid = {e["args"]["sid"]: e for e in spans}
+    outer = [e for e in spans if e["name"] == "t.outer"]
+    inner = [e for e in spans if e["name"] == "t.inner"]
+    assert len(outer) == n_threads * rounds and len(inner) == 3 * len(outer)
+    assert all(e["args"]["parent"] is None for e in outer)
+    for e in inner:
+        parent = by_sid[e["args"]["parent"]]
+        assert parent["name"] == "t.outer" and parent["tid"] == e["tid"]
+        assert (e["args"]["thread"], e["args"]["r"]) == \
+            (parent["args"]["thread"], parent["args"]["r"])
 
 
 # ---------------------------------------------------------------------------

@@ -81,6 +81,26 @@ def test_assignment_program_compiles(one_chip):
     _assert_named_kernel(compiled)
 
 
+def test_batched_assignment_program_compiles_over_four_chips(topo):
+    """The fleets' batched program: 8 slots at bucket 4096, two on each
+    chip of the 2x2 host, vmapped and shard_mapped over the four."""
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core.round import _batch_program
+
+    slots, bucket, cols = 8, 4096, 6
+    cells = NamedSharding(Mesh(np.asarray(topo.devices), ("cells",)),
+                          P("cells"))
+    compiled = _batch_program(
+        tuple(topo.devices), soften=False, sigma=10.0, impl="pallas",
+        eps_min=0.005, interpret=False).lower(
+        _shape(cells, (slots, 3, bucket - 1, cols)),
+        _shape(cells, (slots, bucket - 1, 2)),
+        _shape(cells, (slots, cols))).compile()
+    _assert_named_kernel(compiled)
+
+
 def test_temporal_program_compiles(one_chip):
     from repro.core import footprint
     from repro.core.round import _temporal_program
